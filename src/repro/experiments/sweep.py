@@ -49,6 +49,7 @@ CLI::
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from dataclasses import dataclass
 from typing import Any, Callable, Optional
@@ -383,9 +384,10 @@ def make_batched_run_rounds(loss_fn: Callable, algorithm,
             return (st, ds), {"metrics": mets, "evals": evals}
         return _replicate(st), {"metrics": mets, "evals": evals}
 
-    spmd_batch = "batch" if shard_mesh is not None else None
+    # the trajectory axis is "batch" on every mesh; without one the name
+    # binds nothing
     init_batch = jax.jit(jax.vmap(init_point, in_axes=(0, 0, 0, 0, None, 0),
-                                  spmd_axis_name=spmd_batch))
+                                  spmd_axis_name="batch"))
     # carry_out segments update the [B]-state in place (donated (st, ds))
     # so chaining rungs never doubles the state footprint; the historical
     # one-shot mode keeps its undonated signature untouched
@@ -394,21 +396,35 @@ def make_batched_run_rounds(loss_fn: Callable, algorithm,
     donate = (0, 1) if (carry_out and donate_carry) else ()
     scan_batch = jax.jit(jax.vmap(scan_point,
                                   in_axes=(0, 0, 0, 0, 0, None, 0),
-                                  spmd_axis_name=spmd_batch),
+                                  spmd_axis_name="batch"),
                          donate_argnums=donate)
+
+    def placed(batch: CellBatch):
+        """Trace and run under the mesh the batch is laid out on, when it
+        spans several devices (``jax.set_mesh``): that context is how the
+        kernel dispatch layer knows to wrap its Mosaic kernels, which XLA
+        cannot partition, in a ``shard_map`` (``repro.kernels.dispatch``)."""
+        mesh = shard_mesh
+        if mesh is None:
+            sh = getattr(batch.p_base, "sharding", None)
+            if isinstance(sh, NamedSharding) and sh.mesh.size > 1:
+                mesh = sh.mesh
+        return contextlib.nullcontext() if mesh is None else jax.set_mesh(mesh)
 
     def init(batch: CellBatch):
         """The batched init stage alone: the [B] (FedState, ds_state) carry."""
-        return init_batch(batch.keys, batch.p_base, batch.hparams,
-                          batch.data, batch.shared, batch.algo_id)
+        with placed(batch):
+            return init_batch(batch.keys, batch.p_base, batch.hparams,
+                              batch.data, batch.shared, batch.algo_id)
 
     def step(carry, batch: CellBatch):
         """One scan dispatch from an existing carry. In ``carry_out`` mode
         this is the resumable segment: returns ``(next_carry, out)`` and (on
         donating backends) consumes the passed carry's buffers."""
         st, ds = carry
-        return scan_batch(st, ds, batch.keys["data"], batch.p_base,
-                          batch.hparams, batch.shared, batch.algo_id)
+        with placed(batch):
+            return scan_batch(st, ds, batch.keys["data"], batch.p_base,
+                              batch.hparams, batch.shared, batch.algo_id)
 
     def run(batch: CellBatch):
         return step(init(batch), batch)
@@ -590,4 +606,7 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -3,8 +3,9 @@
 One resolution layer decides how the sweep engine's server aggregation
 executes, so the same traced program runs everywhere:
 
-- ``"compiled"`` — the Pallas kernel compiled for the accelerator
-  (``interpret=False``); the default on TPU/GPU backends.
+- ``"compiled"`` — the Pallas kernel compiled for the TPU
+  (``interpret=False``); the default on TPU. The kernels are Mosaic (TPU)
+  kernels, so no other platform defaults to it.
 - ``"interpret"`` — the Pallas kernel in interpret mode: the kernel body is
   traced to plain XLA ops, so it runs (and is differentiable/shardable)
   anywhere; the default on CPU. On CPU this is bitwise identical to the
@@ -15,6 +16,13 @@ executes, so the same traced program runs everywhere:
 Overrides (highest precedence first): an explicit ``backend=`` argument,
 the ``REPRO_KERNEL_BACKEND`` environment variable (``compiled`` /
 ``interpret`` / ``xla``), then the per-platform default above.
+
+Several devices: XLA cannot partition a Mosaic kernel, so under a context
+mesh of more than one device (``jax.set_mesh``, entered by the sweep
+runner when its batch is laid out on a mesh) a compiled kernel runs inside
+a ``shard_map`` whose per-call view is replicated; the enclosing vmaps'
+``spmd_axis_name`` maps their batched dims (trajectories, clients) onto
+mesh axes, so each device runs the kernel on its own slice.
 
 Whether the engine uses the kernel at all is a separate knob, threaded as
 ``use_kernel`` through ``AlgorithmSpec.aggregate`` -> ``make_round_fn`` ->
@@ -43,10 +51,12 @@ differently at one-ulp level, see ``tests/test_kernels.py``):
 from __future__ import annotations
 
 import os
+from functools import partial
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels.masked_agg import (
     OP_ALL,
@@ -76,11 +86,11 @@ FUSED_OPS = {
 
 def resolve_backend(backend: Optional[str] = None) -> str:
     """The kernel execution backend: explicit arg > ``REPRO_KERNEL_BACKEND``
-    env var > platform default (compiled on tpu/gpu, interpret on cpu)."""
+    env var > platform default (compiled on tpu, interpret elsewhere)."""
     if backend is None:
         backend = os.environ.get(_ENV_BACKEND) or None
     if backend is None:
-        backend = ("compiled" if jax.default_backend() in ("tpu", "gpu")
+        backend = ("compiled" if jax.default_backend() == "tpu"
                    else "interpret")
     if backend not in BACKENDS:
         raise ValueError(f"unknown kernel backend {backend!r}; "
@@ -113,12 +123,22 @@ def resolve_attention_backend(backend: Optional[str] = None) -> str:
     if backend is None:
         backend = os.environ.get(_ENV_BACKEND) or None
     if backend is None:
-        backend = ("compiled" if jax.default_backend() in ("tpu", "gpu")
+        backend = ("compiled" if jax.default_backend() == "tpu"
                    else "xla")
     if backend not in BACKENDS:
         raise ValueError(f"unknown kernel backend {backend!r}; "
                          f"available: {BACKENDS}")
     return backend
+
+
+def _on_context_mesh(kernel):
+    """``kernel`` as is, or inside a replicated-view ``shard_map`` over the
+    context mesh when that mesh spans several devices (see the module
+    docstring)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return kernel
+    return jax.shard_map(kernel, in_specs=P(), out_specs=P(), check_vma=False)
 
 
 def attention(q, k, v, *, kind="full", window=4096, logit_softcap=0.0,
@@ -156,10 +176,13 @@ def attention(q, k, v, *, kind="full", window=4096, logit_softcap=0.0,
     n_rep = h // k.shape[2]
     kr = ref._repeat_kv(k, n_rep).transpose(0, 2, 1, 3)
     vr = ref._repeat_kv(v, n_rep).transpose(0, 2, 1, 3)
-    out = flash_attention(q.transpose(0, 2, 1, 3), kr, vr, causal=True,
-                          window=window if kind == "swa" else 0,
-                          logit_softcap=logit_softcap,
-                          interpret=(backend == "interpret"))
+    flash = partial(flash_attention, causal=True,
+                    window=window if kind == "swa" else 0,
+                    logit_softcap=logit_softcap,
+                    interpret=(backend == "interpret"))
+    if backend == "compiled":
+        flash = _on_context_mesh(flash)
+    out = flash(q.transpose(0, 2, 1, 3), kr, vr)
     return out.transpose(0, 2, 1, 3)
 
 
@@ -174,8 +197,11 @@ def fused_agg(x, mask, op, prev, p, *, block_n: int = 4096,
     backend = resolve_backend(backend)
     if backend == "xla":
         return fused_masked_agg_ref(x, mask, op, prev, p)
-    return fused_masked_agg(x, mask, op, prev, p, block_n=block_n,
-                            interpret=(backend == "interpret"))
+    if backend == "interpret":
+        return fused_masked_agg(x, mask, op, prev, p, block_n=block_n,
+                                interpret=True)
+    kernel = partial(fused_masked_agg, block_n=block_n, interpret=False)
+    return _on_context_mesh(kernel)(x, mask, op, prev, p)
 
 
 def fused_agg_pytree(x_star: Pytree, mask, op, server: Pytree, p, *,

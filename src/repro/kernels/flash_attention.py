@@ -4,6 +4,13 @@ Online-softmax with explicit VMEM tiling: grid (B*H, Tq/bq, Tk/bk), the KV
 axis innermost so the running (m, l, acc) triple lives in VMEM scratch across
 KV steps and the output tile is written once on the last step. Block shapes
 are MXU-aligned (multiples of 128 on the matmul dims).
+
+Gradients: :func:`flash_attention` is a ``jax.custom_vjp``. The forward pass
+is the Pallas kernel; the backward pass is the VJP of the float32 reference
+(``repro.kernels.ref.flash_attention_ref``), recomputed from the saved
+``q, k, v``. It materializes the ``[T, T]`` scores per head, which is cheap
+at the federated clients' training lengths; a Pallas backward kernel is
+left to a later change.
 """
 from __future__ import annotations
 
@@ -13,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.ref import flash_attention_ref
 
 NEG_INF = -1e30
 
@@ -56,12 +65,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window",
-                                             "logit_softcap", "bq", "bk",
-                                             "interpret"))
-def flash_attention(q, k, v, *, causal=True, window=0, logit_softcap=0.0,
-                    bq=128, bk=128, interpret=True):
-    """q, k, v: [B, H, T, D] (same head count; GQA handled by the wrapper)."""
+def _flash_forward(q, k, v, causal, window, logit_softcap, bq, bk,
+                   interpret):
     b, h, t, d = q.shape
     bq = min(bq, t)
     bk = min(bk, t)
@@ -91,3 +96,35 @@ def flash_attention(q, k, v, *, causal=True, window=0, logit_softcap=0.0,
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(b, h, t, d)
+
+
+_flash = jax.custom_vjp(_flash_forward, nondiff_argnums=tuple(range(3, 9)))
+
+
+def _flash_fwd(q, k, v, *static):
+    return _flash_forward(q, k, v, *static), (q, k, v)
+
+
+def _flash_bwd(causal, window, logit_softcap, bq, bk, interpret, res, g):
+    def ref(q, k, v):
+        f32 = jnp.float32
+        return flash_attention_ref(q.astype(f32), k.astype(f32),
+                                   v.astype(f32), causal=causal,
+                                   window=window, logit_softcap=logit_softcap)
+
+    _, vjp = jax.vjp(ref, *res)
+    return vjp(g.astype(jnp.float32))
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "window",
+                                             "logit_softcap", "bq", "bk",
+                                             "interpret"))
+def flash_attention(q, k, v, *, causal=True, window=0, logit_softcap=0.0,
+                    bq=128, bk=128, interpret: bool):
+    """q, k, v: [B, H, T, D] (same head count; GQA handled by the wrapper).
+
+    Differentiable: see the module docstring for the backward pass."""
+    return _flash(q, k, v, causal, window, logit_softcap, bq, bk, interpret)

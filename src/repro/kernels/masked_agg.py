@@ -26,13 +26,19 @@ Branch opcodes (see ``repro.kernels.dispatch``):
 All arithmetic is fp32 regardless of input dtype (fp32 accumulation for
 bf16 inputs); outputs are fp32 and callers cast back per leaf. The kernel
 tiles the (flattened) parameter dimension into VMEM-resident blocks and
-keeps the whole (small) client axis per block, so each output element is
-produced in one pass: grid ``(n/bn,)`` (2-D input) or ``(B, n/bn)`` (3-D).
+keeps the whole client axis per block, so each output element is produced
+in one pass: grid ``(n/bn,)`` for 2-D input; the 3-D sweep layout is the
+2-D kernel under ``vmap``, which Pallas lifts to a ``(B, n/bn)`` grid with
+the batch block dimension squeezed (so every block's last two dims are
+either the full array dims or multiples of (8, 128), as TPU tiling needs).
+The block width ``bn`` shrinks as ``m`` grows to keep a block inside the
+VMEM budget (:func:`block_width`).
 
-``interpret=True`` (the CPU default via ``repro.kernels.dispatch``) traces
-the body to plain XLA ops — on CPU the result is bitwise identical to the
-engine's XLA masked-mean path for fp32 leaves; ``interpret=False`` compiles
-the kernel on TPU/GPU (documented tolerance: see README "Kernels").
+``interpret`` has no default: ``interpret=True`` traces the body to plain
+XLA ops (on CPU bitwise identical to the engine's XLA masked-mean path for
+fp32 leaves), ``interpret=False`` compiles the kernel for the TPU
+(documented tolerance: see README "Kernels"). ``repro.kernels.dispatch``
+picks it from the platform.
 """
 from __future__ import annotations
 
@@ -50,6 +56,30 @@ OP_KNOWN_P = 2   # fedavg_known_p: 1/(m * p_i) delta weighting
 
 def _round_up(n: int, mult: int) -> int:
     return -(-n // mult) * mult
+
+
+# VMEM budget of one grid step. v5e gives a Mosaic kernel 16 MiB of scoped
+# VMEM by default; 12 MiB leaves room for the compiler's own scratch. One
+# step holds the double-buffered [m, bn] input block, about four [m, bn]
+# float32 temporaries of the body (x*mask, x-prev and the two weighted
+# deltas), and the two [m, 1] mask/p columns, which VMEM pads to 128 lanes
+# and double-buffers. The whole client axis sits in every block, so bn is
+# what gives: [256, 4096] float32 blocks ran out of VMEM on v5e, m=192
+# still fit. Below bn=128 nothing is left to shrink, which caps the client
+# axis at about 4000 per call (the cohort engine aggregates C, not m).
+VMEM_BUDGET_BYTES = 12 * 2**20
+_F32_TEMPS = 4
+
+
+def block_width(m: int, n: int, itemsize: int, block_n: int = 4096) -> int:
+    """Parameter-axis block width for an ``[m, n]`` aggregation: the
+    largest multiple of 128 (the TPU lane width) that keeps one grid step
+    inside :data:`VMEM_BUDGET_BYTES`, at most ``block_n`` and no wider than
+    ``n`` rounded up to 128."""
+    per_col = m * (2 * itemsize + _F32_TEMPS * 4)
+    fixed = 2 * 2 * m * 128 * 4
+    fit = max(VMEM_BUDGET_BYTES - fixed, 0) // per_col // 128 * 128
+    return max(128, min(block_n, fit, _round_up(n, 128)))
 
 
 # ---------------------------------------------------------------------------
@@ -74,8 +104,7 @@ def _guarded_mean_kernel(mask_ref, prev_ref, x_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def masked_agg(x, mask, prev=None, *, block_n: int = 4096,
-               interpret: bool = True):
+def masked_agg(x, mask, prev=None, *, block_n: int = 4096, interpret: bool):
     """x: [m, n]; mask: [m]. Returns [n] fp32 (active-client mean).
 
     Zero-active semantics: with ``prev=None`` an empty active set yields the
@@ -86,7 +115,7 @@ def masked_agg(x, mask, prev=None, *, block_n: int = 4096,
     ``jnp.where(any_active, masked_mean(...), server)`` semantics.
     """
     m, n = x.shape
-    bn = min(block_n, _round_up(n, 128))
+    bn = block_width(m, n, x.dtype.itemsize, block_n)
     pad = (-n) % bn
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad)))
@@ -169,32 +198,8 @@ def _fused_call_2d(x, mask, op, prev, p, bn: int, interpret: bool):
     )(op, mask, p, prev, x)[0]
 
 
-def _fused_batched_kernel(op_ref, mask_ref, p_ref, prev_ref, x_ref, o_ref):
-    _fused_kernel(op_ref[0], mask_ref[0][..., None], p_ref[0][..., None],
-                  prev_ref, x_ref[0], o_ref)
-
-
-def _fused_call_3d(x, mask, op, prev, p, bn: int, interpret: bool):
-    B, m, np_ = x.shape
-    assert np_ % bn == 0, (np_, bn)   # caller pads n up to a bn multiple
-    return pl.pallas_call(
-        _fused_batched_kernel,
-        grid=(B, np_ // bn),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, m), lambda b, i: (b, 0)),
-            pl.BlockSpec((1, m), lambda b, i: (b, 0)),
-            pl.BlockSpec((1, bn), lambda b, i: (b, i)),
-            pl.BlockSpec((1, m, bn), lambda b, i: (b, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, bn), lambda b, i: (b, i)),
-        out_shape=jax.ShapeDtypeStruct((B, np_), jnp.float32),
-        interpret=interpret,
-    )(op, mask, p, prev, x)
-
-
 def fused_masked_agg(x, mask, op, prev, p, *, block_n: int = 4096,
-                     interpret: bool = True):
+                     interpret: bool):
     """Fused family aggregation over stacked client params.
 
     Shapes — single trajectory: ``x [m, n]``, ``mask [m]``, ``op`` scalar,
@@ -203,27 +208,21 @@ def fused_masked_agg(x, mask, op, prev, p, *, block_n: int = 4096,
     ``[B, n]``: the new server params under the branch each trajectory's
     ``op`` selects (see module docstring for the opcode table).
 
-    The 2-D form also composes with ``jax.vmap`` (Pallas lifts the call to a
-    batched grid), which is how the round engine reaches the sweep layout.
+    The sweep layout is the single-trajectory kernel under ``jax.vmap``
+    (Pallas lifts the call to a batched grid) — the same program the round
+    engine reaches by vmapping over trajectories.
     """
-    if x.ndim == 2:
-        m, n = x.shape
-        bn = min(block_n, _round_up(n, 128))
-        pad = (-n) % bn
-        xp = jnp.pad(x, ((0, 0), (0, pad))) if pad else x
-        prevp = jnp.pad(prev.astype(jnp.float32), (0, pad)).reshape(1, -1)
-        out = _fused_call_2d(
-            xp, mask.astype(jnp.float32).reshape(m, 1),
-            jnp.asarray(op, jnp.int32).reshape(1, 1),
-            prevp, p.astype(jnp.float32).reshape(m, 1), bn, interpret)
-        return out[:n]
-    B, m, n = x.shape
-    bn = min(block_n, _round_up(n, 128))
+    if x.ndim == 3:
+        return jax.vmap(functools.partial(
+            fused_masked_agg, block_n=block_n, interpret=interpret))(
+            x, mask, op, prev, p)
+    m, n = x.shape
+    bn = block_width(m, n, x.dtype.itemsize, block_n)
     pad = (-n) % bn
-    xp = jnp.pad(x, ((0, 0), (0, 0), (0, pad))) if pad else x
-    prevp = jnp.pad(prev.astype(jnp.float32), ((0, 0), (0, pad)))
-    out = _fused_call_3d(
-        xp, mask.astype(jnp.float32),
-        jnp.asarray(op, jnp.int32).reshape(B, 1, 1),
-        prevp, p.astype(jnp.float32), bn, interpret)
-    return out[:, :n]
+    xp = jnp.pad(x, ((0, 0), (0, pad))) if pad else x
+    prevp = jnp.pad(prev.astype(jnp.float32), (0, pad)).reshape(1, -1)
+    out = _fused_call_2d(
+        xp, mask.astype(jnp.float32).reshape(m, 1),
+        jnp.asarray(op, jnp.int32).reshape(1, 1),
+        prevp, p.astype(jnp.float32).reshape(m, 1), bn, interpret)
+    return out[:n]

@@ -1,9 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On this CPU container kernels run with ``interpret=True`` (Pallas executes the
-kernel body in Python); on TPU set ``interpret=False``. The model forward
-paths use the pure-jnp implementations by default — the kernels are the
-TPU-target hot-spot implementations, validated against ``ref.py``.
+Every kernel entry point takes ``interpret`` with no default: a direct
+caller states it, and everything else goes through ``repro.kernels.dispatch``,
+which picks the compiled kernel on TPU and interpret mode or the XLA
+reference elsewhere. The kernels are validated against ``ref.py``.
 """
 from __future__ import annotations
 
@@ -37,7 +37,7 @@ from repro.kernels.ref import (
 from repro.kernels.rwkv6_chunk import rwkv6_chunk
 
 
-def masked_agg_pytree(clients, mask, prev=None, *, interpret: bool = True):
+def masked_agg_pytree(clients, mask, prev=None, *, interpret: bool):
     """FedPBC aggregation over an [m, ...] client-stacked pytree using the
     masked_agg kernel per (flattened) leaf. ``prev`` (a pytree matching the
     server params) folds the empty-active-set guard into the kernel: a
@@ -54,7 +54,7 @@ def masked_agg_pytree(clients, mask, prev=None, *, interpret: bool = True):
 
 
 def gqa_flash_attention(q, k, v, *, causal=True, window=0, logit_softcap=0.0,
-                        interpret: bool = True):
+                        interpret: bool):
     """q: [B, T, H, D]; k, v: [B, T, KV, D] (GQA) -> [B, T, H, D]."""
     b, t, h, d = q.shape
     kv = k.shape[2]

@@ -55,7 +55,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, s_ref, state, *,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def rwkv6_chunk(r, k, v, w, u, s0, *, chunk: int = 64, interpret: bool = True):
+def rwkv6_chunk(r, k, v, w, u, s0, *, chunk: int = 64, interpret: bool):
     """r,k,v,w: [B, H, T, D]; u: [H, D]; s0: [B, H, D, D] fp32.
 
     Returns (o [B,H,T,D] fp32, s_T [B,H,D,D] fp32).

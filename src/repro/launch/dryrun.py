@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry run: lower + compile every (architecture x input shape) on
 the production meshes, print memory/cost analysis, and dump roofline terms.
 
@@ -16,10 +13,14 @@ and extrapolate  total = f1 + (n_periods - 1) * (f2 - f1)
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch smollm-135m --shape train_4k
   PYTHONPATH=src python -m repro.launch.dryrun --all --both-meshes --out experiments/dryrun.json
+
+``main`` asks the CPU backend for 512 host devices (``XLA_FLAGS``) before
+JAX creates its backends; importing this module changes nothing.
 """
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 
@@ -209,6 +210,7 @@ def lower_pair(arch: str, shape_name: str, *, multi_pod: bool = False,
 
 
 def main():
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

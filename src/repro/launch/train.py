@@ -4,7 +4,10 @@
       --rounds 50 --clients 8 --algorithm fedpbc --scheme bernoulli
 
 Runs the FedPBC round engine over the selected architecture on the local
-devices (reduced configs on CPU; full configs are exercised via dryrun.py).
+devices: ``--reduced`` (the default) cuts the model to the CPU-sized
+``reduced()`` variant in float32, ``--full`` keeps every published width
+in the config's own dtype — e.g. the 30-layer, 135M-parameter smollm-135m
+in bfloat16 on one TPU v5e chip (``chip_smoke.py`` runs that path).
 
 Rounds execute on the scanned engine (``repro.core.make_run_rounds``): token
 batches are sampled on device by ``repro.data.lm_source`` and every
@@ -12,17 +15,22 @@ log/checkpoint interval runs as ONE dispatch (``jax.lax.scan`` over the round
 function), instead of one dispatch + host batch upload per round.
 Checkpoints carry the full ``{fed, ds}`` state every --ckpt-every rounds, so
 a restore resumes mid-sweep with the identical trajectory.
+
+``build`` and ``train`` are the two halves of ``main`` for callers that need
+the round program itself (to lower it, or to take one client's gradient)
+before training with it.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
 import time
+from typing import Any, Callable, List
 
 import numpy as np
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
     ap.add_argument("--reduced", action="store_true", default=True)
@@ -41,12 +49,26 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=25)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+@dataclasses.dataclass
+class Trainer:
+    """One launcher run's model config, round program and initial state."""
+
+    cfg: Any
+    loss: Callable          # (params, batch) -> scalar
+    source: Any             # the on-device DataSource
+    run_rounds: Callable    # jitted (state, ds_state, key, num_rounds)
+    state: Any              # FedState
+    ds_state: Any
+    data_key: Any
+
+
+def build(args: argparse.Namespace) -> Trainer:
     import jax
     import jax.numpy as jnp
 
-    from repro.checkpointing import latest_step, restore, save
     from repro.configs import FederationConfig, get_config, reduced
     from repro.core import (
         build_base_probs,
@@ -94,8 +116,20 @@ def main():
     st = init_fed_state(jax.random.PRNGKey(args.seed + 2), params, fed,
                         algo, link, opt)
     ds_state = source.init(jax.random.PRNGKey(args.seed + 3))
-    data_key = jax.random.PRNGKey(args.seed + 4)
+    return Trainer(cfg=cfg, loss=loss, source=source, run_rounds=run_rounds,
+                   state=st, ds_state=ds_state,
+                   data_key=jax.random.PRNGKey(args.seed + 4))
 
+
+def train(tr: Trainer, args: argparse.Namespace) -> List[dict]:
+    """Run ``args.rounds`` rounds (resuming from ``--ckpt-dir``); returns one
+    log entry per scanned chunk: ``{"round", "loss" [chunk], "active"}``.
+    The trainer's state buffers are donated on the chip: ``tr`` holds the
+    final state afterwards."""
+    from repro.checkpointing import latest_step, restore, save
+
+    m = args.clients
+    st, ds_state = tr.state, tr.ds_state
     if args.ckpt_dir:
         last = latest_step(args.ckpt_dir)
         if last is not None:
@@ -117,20 +151,34 @@ def main():
             nxt = min(nxt, t - t % args.ckpt_every + args.ckpt_every)
         return nxt
 
+    log = []
     t0 = time.time()
     start_round = t = int(st.round)
     while t < args.rounds:
         chunk = next_boundary(t) - t
-        st, ds_state, mets = run_rounds(st, ds_state, data_key, chunk)
+        st, ds_state, mets = tr.run_rounds(st, ds_state, tr.data_key, chunk)
+        tr.state, tr.ds_state = st, ds_state
         t += chunk
-        print(f"round {t:4d} loss {float(mets['loss'][-1]):.4f} "
-              f"active {int(mets['num_active'][-1])}/{m} "
+        losses = np.asarray(mets["loss"])
+        log.append({"round": t, "loss": losses,
+                    "active": int(mets["num_active"][-1])})
+        print(f"round {t:4d} loss {float(losses[-1]):.4f} "
+              f"active {log[-1]['active']}/{m} "
               f"mean_staleness {float(np.mean(mets['staleness'][-1])):.1f} "
               f"({(time.time() - t0):.1f}s)", flush=True)
         if args.ckpt_dir and t % args.ckpt_every == 0:
             save(args.ckpt_dir, t, (st, ds_state))
     print(f"done: {args.rounds - start_round} rounds in {time.time() - t0:.1f}s")
+    return log
+
+
+def main(argv=None) -> List[dict]:
+    args = parse_args(argv)
+    return train(build(args), args)
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -1,7 +1,7 @@
 import os
 
-# Smoke tests and benches must see ONE device (the dry-run sets its own
-# 512-device flag in repro.launch.dryrun, which is never imported here).
+# Tests run on the CPU and see ONE device (the dry run sets its own
+# 512-device flag in repro.launch.dryrun.main, which no test calls).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import dataclasses

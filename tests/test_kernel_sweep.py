@@ -71,8 +71,14 @@ def _agg_inputs(key, m=6, empty=False):
 @pytest.mark.parametrize("empty", [False, True])
 def test_fused_aggregate_matches_switch_static(empty):
     """Per-member static dispatch: the fused kernel's (algo_state, server,
-    clients) triple equals the XLA branch exactly — including the
-    zero-active round, where both must preserve the server params."""
+    clients) triple equals the XLA branch — including the zero-active
+    round, where both must preserve the server params exactly.
+
+    With clients active the contract is a tolerance of 3e-7 (about 2.5
+    float32 ulps at |x| ~ 1): both sides run eagerly, op by op, and the
+    XLA of jax 0.9 sums the kernel's fused select body in another order
+    than the engine's standalone reduce, one ulp apart (5.96e-8 observed).
+    The jitted sweep programs stay bitwise (test_sweep_use_kernel_bit_for_bit)."""
     spec = AlgorithmSpec(FAMILY)
     key = jax.random.PRNGKey(3 + empty)
     x_star, server, clients, active, p_t = _agg_inputs(key, empty=empty)
@@ -82,7 +88,12 @@ def test_fused_aggregate_matches_switch_static(empty):
                               p_t, jnp.int32(0))
         got = spec.aggregate(aid, state, server, clients, x_star, active,
                              p_t, jnp.int32(0), use_kernel=True)
-        _assert_trees_equal(got, want)
+        if empty:
+            _assert_trees_equal(got, want)
+            continue
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=3e-7, atol=3e-7)
 
 
 def test_fused_aggregate_matches_switch_traced_batched():

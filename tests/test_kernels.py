@@ -1,5 +1,6 @@
 """Pallas kernels vs. pure-jnp oracles (interpret=True on CPU), with
-shape/dtype sweeps and hypothesis properties."""
+shape/dtype sweeps and hypothesis properties. Every direct kernel call
+states ``interpret``: the entry points have no default."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -47,7 +48,7 @@ def test_masked_agg_sweep(m, n, dtype):
     key = jax.random.PRNGKey(m * n)
     x = jax.random.normal(key, (m, n), jnp.float32).astype(dtype)
     mask = (jax.random.uniform(jax.random.fold_in(key, 1), (m,)) < 0.5)
-    out = masked_agg(x, mask, block_n=256)
+    out = masked_agg(x, mask, block_n=256, interpret=True)
     ref = masked_agg_ref(x, mask)
     tol = 1e-6 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=tol, atol=tol)
@@ -56,7 +57,7 @@ def test_masked_agg_sweep(m, n, dtype):
 def _check_masked_agg(m, n, bits):
     mask = jnp.asarray([(bits >> i) & 1 for i in range(m)], jnp.float32)
     x = jnp.arange(m * n, dtype=jnp.float32).reshape(m, n)
-    out = masked_agg(x, mask, block_n=128)
+    out = masked_agg(x, mask, block_n=128, interpret=True)
     ref = masked_agg_ref(x, mask)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5)
 
@@ -88,7 +89,7 @@ def test_masked_agg_pytree_matches_engine():
     clients = {"a": jax.random.normal(key, (6, 10, 3)),
                "b": jax.random.normal(jax.random.fold_in(key, 1), (6, 5))}
     mask = jnp.asarray([1, 1, 0, 1, 0, 0], jnp.float32)
-    got = masked_agg_pytree(clients, mask)
+    got = masked_agg_pytree(clients, mask, interpret=True)
     want = masked_mean(clients, mask)
     for k in clients:
         np.testing.assert_allclose(np.asarray(got[k]), np.asarray(want[k]),
@@ -107,24 +108,24 @@ def test_masked_agg_zero_active_semantics():
     prev = jax.random.normal(jax.random.fold_in(key, 1), (n,))
     empty = jnp.zeros((m,), bool)
     # legacy / masked_mean semantics: empty -> zeros
-    np.testing.assert_array_equal(np.asarray(masked_agg(x, empty)),
+    np.testing.assert_array_equal(np.asarray(masked_agg(x, empty, interpret=True)),
                                   np.zeros(n, np.float32))
     np.testing.assert_array_equal(np.asarray(masked_mean(x, empty)),
                                   np.zeros((n,), np.float32))
     # guarded semantics: empty -> prev, bit for bit
-    np.testing.assert_array_equal(np.asarray(masked_agg(x, empty, prev)),
+    np.testing.assert_array_equal(np.asarray(masked_agg(x, empty, prev, interpret=True)),
                                   np.asarray(prev, np.float32))
     np.testing.assert_array_equal(
         np.asarray(masked_agg_ref(x, empty, prev)),
         np.asarray(prev, np.float32))
     # with any client active, prev is inert: both forms agree exactly
     some = jnp.arange(m) < 2
-    np.testing.assert_array_equal(np.asarray(masked_agg(x, some, prev)),
-                                  np.asarray(masked_agg(x, some)))
+    np.testing.assert_array_equal(np.asarray(masked_agg(x, some, prev, interpret=True)),
+                                  np.asarray(masked_agg(x, some, interpret=True)))
     # pytree form
     tree_x = {"w": x.reshape(m, 30, 10), "b": x[:, :4]}
     tree_prev = {"w": prev.reshape(30, 10), "b": prev[:4]}
-    got = masked_agg_pytree(tree_x, empty, tree_prev)
+    got = masked_agg_pytree(tree_x, empty, tree_prev, interpret=True)
     for k in tree_x:
         np.testing.assert_array_equal(np.asarray(got[k]),
                                       np.asarray(tree_prev[k]))
@@ -141,7 +142,7 @@ def test_masked_agg_zero_active_semantics():
 # level, so every bitwise assertion below compares jitted callables.
 _fused_jit = jax.jit(
     lambda x, mask, op, prev, p, block_n: fused_masked_agg(
-        x, mask, op, prev, p, block_n=block_n),
+        x, mask, op, prev, p, block_n=block_n, interpret=True),
     static_argnames="block_n")
 _fused_ref_jit = jax.jit(fused_masked_agg_ref)
 
@@ -176,8 +177,9 @@ def test_fused_masked_agg_vs_ref(B, m, n, mask_kind):
     got = _fused_jit(x, mask, ops, prev, p, block_n=128)
     ref = _fused_ref_jit(x, mask, ops, prev, p)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
-    # the native [B, m, n] grid and vmap over the 2-D kernel agree exactly
-    via_vmap = jax.jit(jax.vmap(lambda *a: fused_masked_agg(*a, block_n=128)))(
+    # the [B, m, n] entry and an explicit vmap over the 2-D kernel agree
+    via_vmap = jax.jit(jax.vmap(lambda *a: fused_masked_agg(
+        *a, block_n=128, interpret=True)))(
         x, mask, ops, prev, p)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(via_vmap))
 
@@ -190,7 +192,8 @@ def test_fused_masked_agg_zero_active_preserves_prev():
     x, _, _, prev, p = _fused_case(key, B, m, n)
     mask = jnp.zeros((B, m), bool)
     ops = jnp.asarray([OP_MEAN, OP_ALL, OP_KNOWN_P], jnp.int32)
-    out = fused_masked_agg(x, mask, ops, prev, p, block_n=128)
+    out = fused_masked_agg(x, mask, ops, prev, p, block_n=128,
+                           interpret=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(prev))
 
 
@@ -359,11 +362,41 @@ def test_flash_attention_sweep(b, h, t, d, win, cap, dtype):
     key = jax.random.PRNGKey(t + d)
     q, k, v = (jax.random.normal(jax.random.fold_in(key, i), (b, h, t, d),
                                  jnp.float32).astype(dtype) for i in range(3))
-    out = flash_attention(q, k, v, window=win, logit_softcap=cap)
+    out = flash_attention(q, k, v, window=win, logit_softcap=cap,
+                          interpret=True)
     ref = flash_attention_ref(q, k, v, window=win, logit_softcap=cap)
     tol = 2e-3 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("win,cap", [(0, 0.0), (64, 0.0), (0, 30.0)])
+def test_flash_attention_grad_matches_reference(win, cap):
+    """The custom_vjp backward (the float32 reference's VJP, recomputed
+    from the saved q, k, v) against jax.grad of the reference itself, in
+    float32. The two gradients share the backward math; they differ only
+    through the cotangent, which flows back from the kernel's forward
+    output (online softmax, a different reduction order from the
+    reference's one-shot softmax). That difference is the forward
+    tolerance of test_flash_attention_sweep (2e-3), so the same bound
+    holds here."""
+    key = jax.random.PRNGKey(31)
+    b, h, t, d = 1, 2, 256, 64
+    q, k, v = (jax.random.normal(jax.random.fold_in(key, i), (b, h, t, d))
+               for i in range(3))
+    w = jax.random.normal(jax.random.fold_in(key, 3), (b, h, t, d))
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(
+            fn(q, k, v, window=win, logit_softcap=cap) * w)
+
+    kern = jax.jit(jax.grad(loss(lambda *a, **kw: flash_attention(
+        *a, **kw, interpret=True)), argnums=(0, 1, 2)))(q, k, v)
+    ref = jax.jit(jax.grad(loss(flash_attention_ref),
+                           argnums=(0, 1, 2)))(q, k, v)
+    for g_kern, g_ref in zip(kern, ref):
+        np.testing.assert_allclose(np.asarray(g_kern), np.asarray(g_ref),
+                                   rtol=2e-3, atol=2e-3)
 
 
 def test_gqa_wrapper():
@@ -372,7 +405,7 @@ def test_gqa_wrapper():
     q = jax.random.normal(key, (b, t, h, d))
     k = jax.random.normal(jax.random.fold_in(key, 1), (b, t, kv, d))
     v = jax.random.normal(jax.random.fold_in(key, 2), (b, t, kv, d))
-    out = gqa_flash_attention(q, k, v)
+    out = gqa_flash_attention(q, k, v, interpret=True)
     from repro.models.attention import attention
     ref = attention(q, k, v, kind="full", chunk=64)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=3e-3, atol=3e-3)
@@ -396,7 +429,7 @@ def test_resolve_attention_backend_defaults_and_env(monkeypatch):
     raise."""
     from repro.kernels.dispatch import resolve_attention_backend
     monkeypatch.delenv("REPRO_KERNEL_BACKEND", raising=False)
-    expect = "compiled" if jax.default_backend() in ("tpu", "gpu") else "xla"
+    expect = "compiled" if jax.default_backend() == "tpu" else "xla"
     assert resolve_attention_backend() == expect
     monkeypatch.setenv("REPRO_KERNEL_BACKEND", "interpret")
     assert resolve_attention_backend() == "interpret"
@@ -476,7 +509,7 @@ def test_rwkv6_chunk_sweep(b, h, t, d, chunk):
         jax.random.fold_in(key, 3), (b, h, t, d))))
     u = 0.3 * jax.random.normal(jax.random.fold_in(key, 4), (h, d))
     s0 = 0.1 * jax.random.normal(jax.random.fold_in(key, 5), (b, h, d, d))
-    o, sT = rwkv6_chunk(r, k, v, w, u, s0, chunk=chunk)
+    o, sT = rwkv6_chunk(r, k, v, w, u, s0, chunk=chunk, interpret=True)
     oref, sref = rwkv6_chunk_ref(r, k, v, w, u, s0)
     np.testing.assert_allclose(np.asarray(o), np.asarray(oref), rtol=3e-3, atol=3e-3)
     np.testing.assert_allclose(np.asarray(sT), np.asarray(sref), rtol=3e-3, atol=3e-3)
@@ -496,7 +529,8 @@ def test_rwkv6_kernel_matches_model_path():
     s0 = jnp.zeros((b, h, d, d))
     o_model, s_model = _wkv_chunk_scan(r, k, v, w, u, s0)
     tr = lambda x: x.transpose(0, 2, 1, 3)
-    o_kern, s_kern = rwkv6_chunk(tr(r), tr(k), tr(v), tr(w), u, s0)
+    o_kern, s_kern = rwkv6_chunk(tr(r), tr(k), tr(v), tr(w), u, s0,
+                                 interpret=True)
     np.testing.assert_allclose(np.asarray(tr(o_kern)), np.asarray(o_model),
                                rtol=3e-3, atol=3e-3)
     np.testing.assert_allclose(np.asarray(s_kern), np.asarray(s_model),

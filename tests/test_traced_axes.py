@@ -90,6 +90,26 @@ def _assert_trees_equal(a, b):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
+# Float tolerance where a batched trajectory meets its sequential twin on
+# jax 0.9: the two are separately compiled programs, and XLA may fuse a
+# reduction of the vmapped round in another order, one float32 ulp per
+# affected round (1.9e-9 on 0.02-sized params, relative 1.0e-7, observed
+# after 5 rounds). 2e-6 relative is ~16 ulps. Integer leaves (round
+# counters, keys, active counts) stay exact: they carry no reduction.
+TRAJ_RTOL, TRAJ_ATOL = 2e-6, 1e-8
+
+
+def _assert_trees_close(a, b):
+    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        x, y = np.asarray(x), np.asarray(y)
+        if np.issubdtype(x.dtype, np.floating):
+            np.testing.assert_allclose(x, y, rtol=TRAJ_RTOL, atol=TRAJ_ATOL)
+        else:
+            np.testing.assert_array_equal(x, y)
+
+
 @pytest.mark.parametrize("algo_name,scheme", [
     ("fedpbc", "bernoulli_tv"),
     ("fedavg", "markov_nonhom"),
@@ -125,8 +145,10 @@ def test_traced_points_match_static_sequential_bit_for_bit(algo_name, scheme):
 
 def test_traced_gamma_matches_static_sequential_bit_for_bit():
     """A gamma axis (Eq.-9 dynamics as traced scalars) must reproduce the
-    gamma-baked link process exactly, including the time-varying p_t the
-    known-p algorithms consume."""
+    gamma-baked link process, including the time-varying p_t the known-p
+    algorithms consume: the same activations exactly, float state and
+    losses within ``TRAJ_RTOL`` (the known-p weighting's reduction is one
+    jax-0.9 ulp apart between the two programs)."""
     spec = dataclasses.replace(BASE, gammas=(0.1, 0.9), seeds=(0,))
     task = get_traced_task(spec)
     fed = spec.cell_config("fedavg_known_p", "bernoulli_tv")
@@ -139,10 +161,9 @@ def test_traced_gamma_matches_static_sequential_bit_for_bit():
         st_seq, mets_seq, _ = _sequential_point(
             spec, "fedavg_known_p", "bernoulli_tv", pt, 0, p_base[0],
             chunks=(2, 2, 1))
-        _assert_trees_equal(jax.tree.map(lambda x: x[pi], states), st_seq)
-        for k in METRIC_KEYS:
-            np.testing.assert_array_equal(
-                np.asarray(out["metrics"][k][pi]), np.asarray(mets_seq[k]))
+        _assert_trees_close(jax.tree.map(lambda x: x[pi], states), st_seq)
+        _assert_trees_close({k: out["metrics"][k][pi] for k in METRIC_KEYS},
+                            {k: mets_seq[k] for k in METRIC_KEYS})
 
 
 def test_value_ablation_reuses_one_compile():
@@ -185,18 +206,25 @@ def test_period_override_shares_compile_and_changes_trajectory():
     differing only in the override must hand back the SAME runner with no
     new jit entries — yet the traced ``hp["period"]`` input must actually be
     wired from the override, i.e. the trajectories must differ AND match a
-    sequential run with that period baked into the link process."""
+    sequential run with that period baked into the link process.
+
+    The algorithm is fedavg_known_p, which weights every arrival by its
+    time-varying p_t: the period then moves the server update itself. (With
+    8 clients over 5 rounds the Bernoulli activations alone can coincide
+    across periods, as they do under jax 0.9's random bits.) Float state
+    meets the sequential twin within ``TRAJ_RTOL``, activations exactly."""
+    algo = "fedavg_known_p"
     spec20 = dataclasses.replace(BASE, rounds=5, eval_every=3, seeds=(0,),
                                  fed_overrides=(("period", 20),))
     spec40 = dataclasses.replace(spec20, fed_overrides=(("period", 40),))
 
     # the override reaches the traced input
-    fed20 = spec20.cell_config("fedpbc", "bernoulli_tv")
+    fed20 = spec20.cell_config(algo, "bernoulli_tv")
     batch20 = make_cell_batch(spec20, fed20, get_traced_task(spec20))
     np.testing.assert_array_equal(np.asarray(batch20.hparams["period"]),
                                   np.full((1,), 20.0, np.float32))
 
-    cells20 = run_cell_batch(spec20, "fedpbc", "bernoulli_tv",
+    cells20 = run_cell_batch(spec20, algo, "bernoulli_tv",
                              metric_keys=METRIC_KEYS, mesh=None)
     runner = _runner_for(spec20, fed20, get_traced_task(spec20), METRIC_KEYS)
     n_runners = len(_RUNNER_CACHE)
@@ -205,19 +233,18 @@ def test_period_override_shares_compile_and_changes_trajectory():
         n_entries = (runner.init_batch._cache_size()
                      + runner.scan_batch._cache_size())
 
-    cells40 = run_cell_batch(spec40, "fedpbc", "bernoulli_tv",
+    cells40 = run_cell_batch(spec40, algo, "bernoulli_tv",
                              metric_keys=METRIC_KEYS, mesh=None)
     # one compile serves both periods...
     assert len(_RUNNER_CACHE) == n_runners
-    assert _runner_for(spec40, spec40.cell_config("fedpbc", "bernoulli_tv"),
+    assert _runner_for(spec40, spec40.cell_config(algo, "bernoulli_tv"),
                        get_traced_task(spec40), METRIC_KEYS) is runner
     if has_introspection:
         assert (runner.init_batch._cache_size()
                 + runner.scan_batch._cache_size()) == n_entries
-    # ...but the trajectories differ: period shapes p_of_t, which drives the
-    # Bernoulli activations (num_active is the link process's fingerprint;
-    # a loss difference would only surface once an aggregation diverges)
-    assert not np.array_equal(cells20[0].num_active, cells40[0].num_active)
+    # ...but the trajectories differ: period shapes p_of_t, which the
+    # known-p weighting divides every arrival by
+    assert not np.array_equal(cells20[0].loss, cells40[0].loss)
 
     # and each matches the sequential path with its period BAKED into the
     # link process (cell_config carries the override into fed.period)
@@ -225,13 +252,11 @@ def test_period_override_shares_compile_and_changes_trajectory():
         pt = spec.hparam_points()[0]
         p_base = point_base_probs(spec, pt)
         _, mets_seq, evals_seq = _sequential_point(
-            spec, "fedpbc", "bernoulli_tv", pt, 0, p_base[0], chunks=(3, 2))
-        np.testing.assert_array_equal(np.asarray(cells[0].loss[0]),
-                                      np.asarray(mets_seq["loss"]))
+            spec, algo, "bernoulli_tv", pt, 0, p_base[0], chunks=(3, 2))
         np.testing.assert_array_equal(np.asarray(cells[0].num_active[0]),
                                       np.asarray(mets_seq["num_active"]))
-        np.testing.assert_array_equal(np.asarray(cells[0].test_acc[0]),
-                                      np.asarray(evals_seq))
+        _assert_trees_close((cells[0].loss[0], cells[0].test_acc[0]),
+                            (mets_seq["loss"], evals_seq))
 
 
 def test_label_noise_shared_swap_reuses_compile_without_new_task():
