@@ -174,20 +174,26 @@ def make_round_fn(loss_fn: Callable, optimizer, algorithm,
     def round_fn(state: FedState, batches) -> tuple:
         """batches: pytree with leading [m, s, ...] (per client, per step)."""
         key, k_link = jax.random.split(state.key)
-        active, p_t, link_state = link.sample(state.link_state, state.round, k_link)
+        with jax.named_scope("fed.link"):
+            active, p_t, link_state = link.sample(
+                state.link_state, state.round, k_link)
 
-        starts = algorithm.client_start(state.algo_state, state.server, state.clients)
+        with jax.named_scope("fed.broadcast"):
+            starts = algorithm.client_start(
+                state.algo_state, state.server, state.clients)
 
         run = partial(local_steps, loss_fn, optimizer, s=s)
-        x_star, opt_state, losses = jax.vmap(
-            run, spmd_axis_name=spmd_axis_name)(
-            starts, state.opt_state, batches)
+        with jax.named_scope("fed.local_train"):
+            x_star, opt_state, losses = jax.vmap(
+                run, spmd_axis_name=spmd_axis_name)(
+                starts, state.opt_state, batches)
         if gather_updates is not None:
             x_star, losses = gather_updates((x_star, losses))
 
-        algo_state, server, clients = algorithm.aggregate(
-            state.algo_state, state.server, state.clients, x_star, active,
-            p_t, state.round)
+        with jax.named_scope("fed.aggregate"):
+            algo_state, server, clients = algorithm.aggregate(
+                state.algo_state, state.server, state.clients, x_star, active,
+                p_t, state.round)
 
         last_active = jnp.where(active, state.round, state.last_active)
         new_state = FedState(
@@ -261,20 +267,24 @@ def _make_scale_round_fn(loss_fn, optimizer, algorithm, link, fed_cfg,
     if cohort_size is None:
         def round_fn(state: FedState, batches) -> tuple:
             key, k_link = jax.random.split(state.key)
-            active, p_t, link_state = link.sample(
-                state.link_state, state.round, k_link)
-            starts = bound.client_start(
-                state.algo_state, state.server, state.clients)
-            x_star, opt_state, losses = jax.vmap(
-                run, spmd_axis_name=spmd_axis_name)(
-                starts, state.opt_state, batches)
+            with jax.named_scope("fed.link"):
+                active, p_t, link_state = link.sample(
+                    state.link_state, state.round, k_link)
+            with jax.named_scope("fed.broadcast"):
+                starts = bound.client_start(
+                    state.algo_state, state.server, state.clients)
+            with jax.named_scope("fed.local_train"):
+                x_star, opt_state, losses = jax.vmap(
+                    run, spmd_axis_name=spmd_axis_name)(
+                    starts, state.opt_state, batches)
             if gather_updates is not None:
                 x_star, losses = gather_updates((x_star, losses))
-            in_buffer = state.buffer.in_buffer | active
-            buf, server, commit, bmets = buffered_aggregate(
-                state.buffer, state.server, x_star, active, p_t, knobs,
-                op=op, m_total=m, in_buffer_new=in_buffer)
-            clients = commit_clients(commit, in_buffer, server, x_star)
+            with jax.named_scope("fed.aggregate"):
+                in_buffer = state.buffer.in_buffer | active
+                buf, server, commit, bmets = buffered_aggregate(
+                    state.buffer, state.server, x_star, active, p_t, knobs,
+                    op=op, m_total=m, in_buffer_new=in_buffer)
+                clients = commit_clients(commit, in_buffer, server, x_star)
             last_active = jnp.where(active, state.round, state.last_active)
             new_state = FedState(
                 server=server, clients=clients, opt_state=opt_state,
@@ -299,33 +309,38 @@ def _make_scale_round_fn(loss_fn, optimizer, algorithm, link, fed_cfg,
         key, k_link, k_cohort = jax.random.split(state.key, 3)
         # the link advances over the FULL population (Markov chains etc.
         # keep their dense-time semantics); the cohort sees its gather
-        active_m, p_t_m, link_state = link.sample(
-            state.link_state, state.round, k_link)
-        cohort = sample_cohort(k_cohort, m, C)
-        c_active, c_p = cohort_arrivals(cohort, active_m, p_t_m)
-        batches, ds_state = source.sample_cohort(
-            ds_state, state.round, k_data, cohort)
-        starts = _tile(state.server, C)
-        opt_state = jax.vmap(optimizer.init)(starts)
-        x_star, _, losses = jax.vmap(run, spmd_axis_name=spmd_axis_name)(
-            starts, opt_state, batches)
+        with jax.named_scope("fed.link"):
+            active_m, p_t_m, link_state = link.sample(
+                state.link_state, state.round, k_link)
+            cohort = sample_cohort(k_cohort, m, C)
+            c_active, c_p = cohort_arrivals(cohort, active_m, p_t_m)
+        with jax.named_scope("fed.sample"):
+            batches, ds_state = source.sample_cohort(
+                ds_state, state.round, k_data, cohort)
+        with jax.named_scope("fed.broadcast"):
+            starts = _tile(state.server, C)
+            opt_state = jax.vmap(optimizer.init)(starts)
+        with jax.named_scope("fed.local_train"):
+            x_star, _, losses = jax.vmap(run, spmd_axis_name=spmd_axis_name)(
+                starts, opt_state, batches)
         if gather_updates is not None:
             x_star, losses = gather_updates((x_star, losses))
-        if buffered:
-            in_buffer = state.buffer.in_buffer.at[cohort].set(
-                state.buffer.in_buffer[cohort] | c_active)
-            buf, server, commit, bmets = buffered_aggregate(
-                state.buffer, state.server, x_star, c_active, c_p, knobs,
-                op=op, m_total=C, in_buffer_new=in_buffer)
-            algo_state = state.algo_state
-        else:
-            algo_state, server = spec.aggregate_cohort(
-                algo_id, state.algo_state, state.server, x_star, cohort,
-                c_active, c_p, state.round)
-            buf = state.buffer
-            bmets = {"commit": jnp.float32(1.0),
-                     "buffer_fill": c_active.sum().astype(jnp.float32),
-                     "commit_staleness": jnp.float32(0.0)}
+        with jax.named_scope("fed.aggregate"):
+            if buffered:
+                in_buffer = state.buffer.in_buffer.at[cohort].set(
+                    state.buffer.in_buffer[cohort] | c_active)
+                buf, server, commit, bmets = buffered_aggregate(
+                    state.buffer, state.server, x_star, c_active, c_p, knobs,
+                    op=op, m_total=C, in_buffer_new=in_buffer)
+                algo_state = state.algo_state
+            else:
+                algo_state, server = spec.aggregate_cohort(
+                    algo_id, state.algo_state, state.server, x_star, cohort,
+                    c_active, c_p, state.round)
+                buf = state.buffer
+                bmets = {"commit": jnp.float32(1.0),
+                         "buffer_fill": c_active.sum().astype(jnp.float32),
+                         "commit_staleness": jnp.float32(0.0)}
         last_active = state.last_active.at[cohort].set(
             jnp.where(c_active, state.round, state.last_active[cohort]))
         new_state = FedState(
@@ -383,7 +398,8 @@ def make_round_step(round_fn, source):
 
     def step(state: FedState, ds_state, data_key):
         k_data = jax.random.fold_in(data_key, state.round)
-        batches, ds_state = source.sample(ds_state, state.round, k_data)
+        with jax.named_scope("fed.sample"):
+            batches, ds_state = source.sample(ds_state, state.round, k_data)
         state, metrics = round_fn(state, batches)
         return state, ds_state, metrics
 

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.configs.base import FederationConfig
 from repro.core.algorithms import (
     ALGORITHMS,
@@ -669,16 +670,14 @@ def make_cell_batch(spec: SweepSpec, fed: FederationConfig,
                      data={"idx": idx}, shared=task.shared, algo_id=algo_id)
 
 
-def _run_batch(spec: SweepSpec, algos: Tuple[str, ...], scheme: str, *,
-               metric_keys=("loss", "num_active"),
-               mesh=AUTO, devices=None) -> List[CellResult]:
-    """Run one (state-compatible algorithm group, scheme) cell: ALL algos x
-    hyperparameter points x seeds in one batched program; returns
-    ``CellResult`` rows algo-major, point-major."""
+def _cell_program(spec: SweepSpec, algos: Tuple[str, ...], scheme: str,
+                  metric_keys, mesh, devices) -> tuple:
+    """What one (state-compatible algorithm group, scheme) cell dispatches:
+    ``(task, runner, batch, B_real)``, the batch padded to and committed on
+    the mesh when there is one (B_real is then the size before padding)."""
     task = get_traced_task(spec)
     fed = spec.cell_config(algos[0], scheme)
-    buffered = _has_strategy_axis(spec)
-    if buffered:
+    if _has_strategy_axis(spec):
         metric_keys = tuple(metric_keys) + tuple(
             k for k in _BUFFER_KEYS if k not in metric_keys)
     batch_mesh = resolve_batch_mesh(mesh, devices)
@@ -687,63 +686,87 @@ def _run_batch(spec: SweepSpec, algos: Tuple[str, ...], scheme: str, *,
     mesh2d = batch_mesh if (batch_mesh is not None
                             and "model" in batch_mesh.axis_names) else None
     runner = _runner_for(spec, fed, task, metric_keys, shard_mesh=mesh2d)
-    if batch_mesh is not None:
-        # memoized pad + device_put (shard.run_sharded is the uncached
-        # one-shot equivalent); padding rows are sliced off right here, so
-        # nothing downstream ever sees them
-        sharded, b_real = _sharded_cell_batch(spec, fed, task, batch_mesh,
-                                              algos)
-        states, out = runner(sharded)
-        if sharded.batch_size != b_real:
+    if batch_mesh is None:
+        batch = make_cell_batch(spec, fed, task, algos=algos)
+        return task, runner, batch, batch.batch_size
+    # memoized pad + device_put (shard.run_sharded is the uncached one-shot
+    # equivalent)
+    batch, b_real = _sharded_cell_batch(spec, fed, task, batch_mesh, algos)
+    return task, runner, batch, b_real
+
+
+def _run_batch(spec: SweepSpec, algos: Tuple[str, ...], scheme: str, *,
+               metric_keys=("loss", "num_active"),
+               mesh=AUTO, devices=None) -> List[CellResult]:
+    """Run one (state-compatible algorithm group, scheme) cell: ALL algos x
+    hyperparameter points x seeds in one batched program; returns
+    ``CellResult`` rows algo-major, point-major. Each phase is a host span
+    (``repro.telemetry``): ``sweep.batch``, ``sweep.dispatch``,
+    ``sweep.wait`` (the host blocked on the device), ``sweep.train_eval``,
+    ``sweep.rows``."""
+    with telemetry.span("sweep.batch"):
+        task, runner, batch, b_real = _cell_program(
+            spec, algos, scheme, metric_keys, mesh, devices)
+    with telemetry.span("sweep.dispatch"):
+        states, out = runner(batch)
+        if batch.batch_size != b_real:
+            # padding rows are sliced off right here, so nothing downstream
+            # ever sees them
             states, out = jax.tree.map(lambda x: x[:b_real], (states, out))
-    else:
-        states, out = runner(make_cell_batch(spec, fed, task, algos=algos))
+    with telemetry.span("sweep.wait"):
+        jax.block_until_ready((states, out))
 
-    points = spec.hparam_points()
-    S = len(spec.seeds)
-    if "evals" in out:
-        test_acc = np.asarray(out["evals"])
-        rounds_at = eval_rounds(spec.rounds, spec.eval_every)
-    else:
-        test_acc = np.asarray(jax.vmap(task.eval_test, in_axes=(0, None))(
-            states.server, task.shared))[:, None]
-        rounds_at = [spec.rounds]
-    train_acc = np.asarray(jax.vmap(task.eval_train, in_axes=(0, None))(
-        states.server, task.shared))
-    mets = {k: np.asarray(v) for k, v in out["metrics"].items()}
-    strategies = spec.strategies
-    n_str = len(strategies)
-    B = len(algos) * n_str * len(points) * S
-    # the per-round population the participation summary normalizes by
-    pop = spec.cohort_size if spec.cohort_size is not None \
-        else spec.num_clients
+    with telemetry.span("sweep.train_eval"):
+        if "evals" in out:
+            test_acc = np.asarray(out["evals"])
+            rounds_at = eval_rounds(spec.rounds, spec.eval_every)
+        else:
+            test_acc = np.asarray(jax.vmap(task.eval_test,
+                                           in_axes=(0, None))(
+                states.server, task.shared))[:, None]
+            rounds_at = [spec.rounds]
+        train_acc = np.asarray(jax.vmap(task.eval_train, in_axes=(0, None))(
+            states.server, task.shared))
+    with telemetry.span("sweep.rows"):
+        buffered = _has_strategy_axis(spec)
+        points = spec.hparam_points()
+        S = len(spec.seeds)
+        mets = {k: np.asarray(v) for k, v in out["metrics"].items()}
+        strategies = spec.strategies
+        n_str = len(strategies)
+        B = len(algos) * n_str * len(points) * S
+        # the per-round population the participation summary normalizes by
+        pop = spec.cohort_size if spec.cohort_size is not None \
+            else spec.num_clients
 
-    def rows(a, ai, si, pi):
-        lo = ((ai * n_str + si) * len(points) + pi) * S
-        return a[lo:lo + S]
+        def rows(a, ai, si, pi):
+            lo = ((ai * n_str + si) * len(points) + pi) * S
+            return a[lo:lo + S]
 
-    return [
-        CellResult(
-            algo=algo, scheme=scheme, seeds=tuple(spec.seeds),
-            rounds=spec.rounds, eval_rounds=rounds_at,
-            test_acc=rows(test_acc, ai, si, pi),
-            train_acc=rows(train_acc, ai, si, pi),
-            loss=rows(mets.get("loss", np.zeros((B, 0))), ai, si, pi),
-            num_active=rows(mets.get("num_active", np.zeros((B, 0))),
-                            ai, si, pi),
-            hparams=dict(pt),
-            strategy=strat.name,
-            # plain dense synchronous cells keep the historical two-key
-            # summary; participation only appears where it is informative
-            # (cohort mode normalizes by C, buffered rows by the buffer pool)
-            num_clients=(pop if (strat.name != "sync"
-                                 or spec.cohort_size is not None) else 0),
-            commit=(rows(mets["commit"], ai, si, pi) if buffered else None),
-            commit_staleness=(rows(mets["commit_staleness"], ai, si, pi)
-                              if buffered else None))
-        for ai, algo in enumerate(algos)
-        for si, strat in enumerate(strategies)
-        for pi, pt in enumerate(points)]
+        return [
+            CellResult(
+                algo=algo, scheme=scheme, seeds=tuple(spec.seeds),
+                rounds=spec.rounds, eval_rounds=rounds_at,
+                test_acc=rows(test_acc, ai, si, pi),
+                train_acc=rows(train_acc, ai, si, pi),
+                loss=rows(mets.get("loss", np.zeros((B, 0))), ai, si, pi),
+                num_active=rows(mets.get("num_active", np.zeros((B, 0))),
+                                ai, si, pi),
+                hparams=dict(pt),
+                strategy=strat.name,
+                # plain dense synchronous cells keep the historical two-key
+                # summary; participation only appears where it is
+                # informative (cohort mode normalizes by C, buffered rows by
+                # the buffer pool)
+                num_clients=(pop if (strat.name != "sync"
+                                     or spec.cohort_size is not None) else 0),
+                commit=(rows(mets["commit"], ai, si, pi) if buffered
+                        else None),
+                commit_staleness=(rows(mets["commit_staleness"], ai, si, pi)
+                                  if buffered else None))
+            for ai, algo in enumerate(algos)
+            for si, strat in enumerate(strategies)
+            for pi, pt in enumerate(points)]
 
 
 def run_cell_batch(spec: SweepSpec, algo: str, scheme: str, *,
@@ -782,6 +805,40 @@ def run_cell(spec: SweepSpec, algo: str, scheme: str, *,
                           mesh=mesh, devices=devices)[0]
 
 
+def _algo_groups(spec: SweepSpec) -> List[Tuple[str, ...]]:
+    """The spec's algorithms grouped into state-compatible families, each
+    group one batched program, in first-appearance order."""
+    groups: Dict[Tuple[str, ...], List[str]] = {}
+    for algo in dict.fromkeys(spec.algorithms):   # unique, in order
+        groups.setdefault(algo_family(algo), []).append(algo)
+    return [tuple(g) for g in groups.values()]
+
+
+_HLO_CACHE: Dict[tuple, Tuple[str, ...]] = {}
+
+
+def sweep_hlo(spec: SweepSpec) -> Tuple[str, ...]:
+    """The optimized HLO text of every program ``run_sweep(spec)``
+    dispatches: each (algorithm group, scheme) cell's init and scan stages,
+    lowered for the batch ``run_sweep`` builds and compiled as it compiles
+    them (with the persistent compile cache, a load). Memoized per spec and
+    default matmul precision, which changes the program.
+    ``repro.telemetry.stage_seconds`` reads device time by stage through
+    these texts."""
+    key = (spec, str(jax.config.jax_default_matmul_precision))
+    if key not in _HLO_CACHE:
+        texts = []
+        for scheme in spec.schemes:
+            for group in _algo_groups(spec):
+                _, runner, batch, _ = _cell_program(
+                    spec, group, scheme, ("loss", "num_active"), AUTO, None)
+                texts += [lowered.compile().as_text()
+                          for lowered in runner.lower(batch)]
+        _HLO_CACHE[key] = tuple(texts)
+    return _HLO_CACHE[key]
+
+
+@telemetry.span("sweep.run")
 def run_sweep(spec: SweepSpec, *, store: Optional[ResultsStore] = None,
               suite: str = "sweep",
               metric_keys=("loss", "num_active"),
@@ -803,9 +860,6 @@ def run_sweep(spec: SweepSpec, *, store: Optional[ResultsStore] = None,
             spec.cell_config(algo, scheme)
     cells = []
     for scheme in spec.schemes:
-        groups: Dict[Tuple[str, ...], List[str]] = {}
-        for algo in dict.fromkeys(spec.algorithms):   # unique, in order
-            groups.setdefault(algo_family(algo), []).append(algo)
         by_algo: Dict[str, List[CellResult]] = {}
         n_points = len(spec.hparam_points()) * len(spec.strategies)
         pending = list(spec.algorithms)     # emission order (per occurrence)
@@ -837,8 +891,8 @@ def run_sweep(spec: SweepSpec, *, store: Optional[ResultsStore] = None,
         # family (e.g. mifa's [m, ...] memory OOMing) never discards rows an
         # earlier family already computed
         try:
-            for group in groups.values():
-                results = _run_batch(spec, tuple(group), scheme,
+            for group in _algo_groups(spec):
+                results = _run_batch(spec, group, scheme,
                                      metric_keys=metric_keys,
                                      mesh=mesh, devices=devices)
                 for ai, algo in enumerate(group):

@@ -364,7 +364,9 @@ def make_batched_run_rounds(loss_fn: Callable, algorithm,
 
         def chunk(carry, _):
             carry, mets = run_span(carry, eval_every)
-            return carry, (mets, eval_fn(carry[0].server, shared))
+            with jax.named_scope("fed.eval"):
+                evals = eval_fn(carry[0].server, shared)
+            return carry, (mets, evals)
 
         carry, (mets, evals) = jax.lax.scan(chunk, (st, ds), None,
                                             length=n_chunks)
@@ -377,8 +379,9 @@ def make_batched_run_rounds(loss_fn: Callable, algorithm,
             carry, tail = run_span(carry, rem)
             mets = jax.tree.map(
                 lambda a, b: jnp.concatenate([a, b], 0), mets, tail)
-            evals = jnp.concatenate(
-                [evals, eval_fn(carry[0].server, shared)[None]])
+            with jax.named_scope("fed.eval"):
+                last = eval_fn(carry[0].server, shared)
+            evals = jnp.concatenate([evals, last[None]])
         st, ds = carry
         if carry_out:
             return (st, ds), {"metrics": mets, "evals": evals}
@@ -429,8 +432,22 @@ def make_batched_run_rounds(loss_fn: Callable, algorithm,
     def run(batch: CellBatch):
         return step(init(batch), batch)
 
+    def lower(batch: CellBatch):
+        """The init and scan stages lowered for ``batch`` as ``run``
+        dispatches them (``jax.stages.Lowered``; the scan's carry is the
+        shape the init stage returns)."""
+        args = (batch.keys, batch.p_base, batch.hparams, batch.data,
+                batch.shared, batch.algo_id)
+        with placed(batch):
+            st, ds = jax.eval_shape(init_batch, *args)
+            return (init_batch.lower(*args),
+                    scan_batch.lower(st, ds, batch.keys["data"],
+                                     batch.p_base, batch.hparams,
+                                     batch.shared, batch.algo_id))
+
     run.init = init
     run.step = step
+    run.lower = lower
     run.init_batch = init_batch
     run.scan_batch = scan_batch
     run.shard_mesh = shard_mesh

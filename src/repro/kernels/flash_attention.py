@@ -94,6 +94,7 @@ def _flash_forward(q, k, v, causal, window, logit_softcap, bq, bk,
             pltpu.VMEM((bq, d), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qf, kf, vf)
     return out.reshape(b, h, t, d)
 

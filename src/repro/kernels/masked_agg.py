@@ -132,6 +132,7 @@ def masked_agg(x, mask, prev=None, *, block_n: int = 4096, interpret: bool):
             out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
             out_shape=jax.ShapeDtypeStruct((np_,), jnp.float32),
             interpret=interpret,
+            name="masked_mean",
         )(mask2, x)
         return out[:n]
     prev2 = jnp.pad(prev.astype(jnp.float32), (0, pad)).reshape(1, np_)
@@ -146,6 +147,7 @@ def masked_agg(x, mask, prev=None, *, block_n: int = 4096, interpret: bool):
         out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((np_,), jnp.float32),
         interpret=interpret,
+        name="guarded_masked_mean",
     )(mask2, prev2, x)
     return out[:n]
 
@@ -195,6 +197,7 @@ def _fused_call_2d(x, mask, op, prev, p, bn: int, interpret: bool):
         out_specs=pl.BlockSpec((1, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, np_), jnp.float32),
         interpret=interpret,
+        name="fused_masked_agg",
     )(op, mask, p, prev, x)[0]
 
 

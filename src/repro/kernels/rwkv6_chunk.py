@@ -91,5 +91,6 @@ def rwkv6_chunk(r, k, v, w, u, s0, *, chunk: int = 64, interpret: bool):
         ],
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
         interpret=interpret,
+        name="rwkv6_chunk",
     )(rf, kf, vf, wf, uf, sf)
     return o.reshape(b, h, t, d), s_out.reshape(b, h, d, d)

@@ -152,6 +152,18 @@ def test_stateful_cohort_engine_runs_and_touches_cohort_rows_only(algo):
         assert unchanged.sum() >= m - 5 * C
 
 
+def test_buffered_cohort_round_reports_the_buffers_metrics():
+    """Cohort draws into a buffer larger than three rounds of arrivals: no
+    round commits, and the fill counts every arrival so far."""
+    strat = Strategy("buffered", buffer_size=32, deadline_rounds=50)
+    run, st, ds = _quadratic_setup(64, 8, strategy=strat)
+    _, _, mets = run(st, ds, jax.random.PRNGKey(3), 3)
+    assert np.asarray(mets["commit"]).tolist() == [0.0, 0.0, 0.0]
+    fill = np.asarray(mets["buffer_fill"])
+    np.testing.assert_array_equal(fill,
+                                  np.cumsum(np.asarray(mets["num_active"])))
+
+
 def test_buffered_strategy_refused_for_stateful_rules():
     m = 8
     fed = FederationConfig(algorithm="fedau", num_clients=m, local_steps=2)

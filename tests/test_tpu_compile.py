@@ -15,6 +15,7 @@ the kernels are called with ``interpret=False`` (or ``backend="compiled"``)
 explicitly.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -41,9 +42,17 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, *args):
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+def _compile(fn, *args, kernel: str):
+    """Compile ``fn`` for the described chip and check that the program
+    runs the Mosaic kernel under its stable ``name=``: the kernel's
+    instruction carries it (``vmap_`` prefixed under vmap), and a device
+    trace names the kernel's op by that instruction."""
+    lowered = jax.jit(fn).lower(*args)
+    assert kernel in lowered.as_text()
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(rf"%[\w.]*{kernel}[\w.]* = ", text), kernel
     return compiled
 
 
@@ -67,7 +76,7 @@ def test_fused_agg_vmap_on_mlp_leaves(one_chip):
     server = {k: s((B,) + v) for k, v in leaves.items()}
     agg = jax.vmap(functools.partial(fused_agg_pytree, backend="compiled"))
     _compile(agg, x_star, s((B, m), jnp.bool_), s((B,), jnp.int32), server,
-             s((B, m)))
+             s((B, m)), kernel="fused_masked_agg")
 
 
 @pytest.mark.parametrize("m", [100, 256, 1000])
@@ -79,10 +88,12 @@ def test_fused_masked_agg_lowers(one_chip, form, m):
     n = 65536
     kernel = functools.partial(fused_masked_agg, interpret=False)
     if form == "2d":
-        compiled = _compile(kernel, *_agg_args(one_chip, (), m, n))
+        compiled = _compile(kernel, *_agg_args(one_chip, (), m, n),
+                            kernel="fused_masked_agg")
     else:
         fn = jax.vmap(kernel) if form == "vmap" else kernel
-        compiled = _compile(fn, *_agg_args(one_chip, (4,), m, n))
+        compiled = _compile(fn, *_agg_args(one_chip, (4,), m, n),
+                            kernel="fused_masked_agg")
     assert compiled.memory_analysis() is not None
 
 
@@ -93,11 +104,12 @@ def test_flash_attention_forward_and_grad_lower(one_chip, t):
     custom_vjp gradient both compile."""
     q, k, v = (_spec(one_chip, (2, 9, t, 64), jnp.bfloat16) for _ in range(3))
     attn = functools.partial(flash_attention, interpret=False)
-    _compile(attn, q, k, v)
+    _compile(attn, q, k, v, kernel="flash_attention")
 
     def loss(q, k, v):
         return jnp.sum(attn(q, k, v).astype(jnp.float32))
 
     # value_and_grad, as the round engine calls it: the loss keeps the
     # forward kernel live next to the backward pass
-    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, k, v)
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, k, v,
+             kernel="flash_attention")
