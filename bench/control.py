@@ -2,26 +2,33 @@
 """Readings that set and test a cell's limits (not part of a benchmark run).
 
     python3 bench/control.py --workload <name> --seeds 1,2,3 \
-        [--controls high,bf16] [--faults frozen,half_batch,altered]
+        [--growth 1,3,10,25,50] [--controls high,bf16] \
+        [--faults frozen,half_batch,altered]
 
-For each seed, in one process: the numbers ``correct`` compares, read from
+In one process: for each seed, the numbers ``correct`` compares, read from
 the program's timed path as a run reads them (set-up and one window
-call, no timing); the same numbers from the control, which is the
-program at a lower matrix-product precision where the program has that
-path (``jax.default_matmul_precision``: ``highest``, ``high``,
-``default``), and otherwise the reference at that precision put in the
-program's place (``bf16``, :mod:`bench.refs.precision`); and from the
-program with one fault planted underneath (see :data:`FAULTS`). Each
-reading is one JSON line on standard output.
+call, no timing), with the trajectory and leaf behind each worst change
+gap, and with ``--growth`` the worst change gap of a call over each of
+those round counts against the reference over as many; then the same
+numbers from the control, which is the program at a lower matrix-product
+precision where the program has that path
+(``jax.default_matmul_precision``: ``highest``, ``high``, ``default``),
+and otherwise the reference at that precision put in the program's place
+(``bf16``, :mod:`bench.refs.precision`); then from the program with one
+fault planted underneath (see :data:`FAULTS`). Each reading is one JSON
+line on standard output.
 """
 from __future__ import annotations
 
 import argparse
 import contextlib
+import dataclasses
 import json
 import sys
 from pathlib import Path
 from unittest import mock
+
+import numpy as np
 
 ROOT = Path(__file__).resolve().parent.parent
 if str(ROOT) not in sys.path:
@@ -99,26 +106,73 @@ def altered():
 FAULTS = {"frozen": frozen, "half_batch": half_batch, "altered": altered}
 
 
-def program_readings(cell, seed, precision=None, fault=None):
-    """The compared numbers of a run's timed path at ``seed``."""
+def program_readings(cell, seed, precision=None, fault=None, growth=()):
+    """The compared numbers of a run's timed path at ``seed``; with
+    ``growth``, what :func:`worst_gaps` says of the program's server
+    after a call over each of those round counts. The program is traced
+    as the last call left it: :func:`main` clears JAX's caches where the
+    precision or the fault changes."""
     config = dict(cell.config)
     if precision is not None:
         config["matmul_precision"] = precision
     wl = cell.driver.Workload(config, cell.traffic,
                               bench_run.program_seed(seed))
-    import jax
-
-    jax.clear_caches()      # retrace: a planted fault or precision applies
     with (FAULTS[fault]() if fault else contextlib.nullcontext()):
         wl.prepare()
         wl.step()           # one window call, whose output is compared
-    wl.release()
-    return wl.check()
+        wl.release()
+        rows = wl.rows(wl.last)
+        calls = {r: wl.call_servers(dataclasses.replace(
+            wl.spec, rounds=r, eval_every=r)) for r in growth}
+    keep = sorted({*growth, wl.early_spec.rounds, wl.spec.rounds})
+    refs = wl.reference(wl.last, keep=keep)
+    readings = wl.compare(rows, refs)
+    if fault or precision:
+        return readings, {}
+    trajs = [t for _, _, t in wl.trajectories_of(wl.last)]
+    detail = {"worst": {
+        name: worst_gaps(cell.driver, trajs, [p["servers"][r] for p in rows],
+                         refs, r)
+        for name, r in (("change_gap", wl.early_spec.rounds),
+                        ("call_change_gap", wl.spec.rounds))}}
+    detail["loss_gap_by_round"] = loss_gap_by_round(trajs, rows, refs)
+    if growth:
+        detail["growth"] = {r: dict(
+            worst_gaps(cell.driver, trajs, servers, refs, r),
+            repeats_window=bool(cell.driver.same_rounds(cells, wl.last, r)))
+            for r, (cells, servers) in calls.items()}
+    return readings, detail
+
+
+def worst_gaps(driver, trajs, servers, refs, rounds):
+    """The worst leaf gap (``driver.leaf_gaps``) of the program's
+    ``servers`` against the reference's after ``rounds`` rounds: its
+    trajectory and leaf, and the worst of each algorithm."""
+    worst, by_algo = None, {}
+    for traj, server, r in zip(trajs, servers, refs):
+        for leaf, gap in driver.leaf_gaps(server, r["servers"][rounds],
+                                          r["init"]).items():
+            by_algo[traj.algo] = max(by_algo.get(traj.algo, 0.0), gap)
+            if worst is None or gap > worst["gap"]:
+                worst = {"gap": gap, "algo": traj.algo, "lr": traj.lr,
+                         "seed": traj.seed, "leaf": leaf}
+    return dict(worst or {"gap": 0.0}, by_algo=by_algo)
+
+
+def loss_gap_by_round(trajs, rows, refs):
+    """Each algorithm's worst relative loss gap in every round of the
+    window call."""
+    out = {}
+    for traj, p, r in zip(trajs, rows, refs):
+        gap = (np.abs(np.asarray(p["loss"], np.float64) - r["loss"])
+               / np.abs(r["loss"]))
+        out[traj.algo] = np.maximum(out.get(traj.algo, 0.0), gap)
+    return {algo: [float(g) for g in gaps] for algo, gaps in out.items()}
 
 
 def control_readings(cell, seed, mode):
     if mode in PROGRAM_PRECISIONS:
-        return program_readings(cell, seed, precision=mode)
+        return program_readings(cell, seed, precision=mode)[0]
     return cell.driver.Workload(cell.config, cell.traffic,
                                 bench_run.program_seed(seed)).control(mode)
 
@@ -127,6 +181,9 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", required=True)
+    ap.add_argument("--growth", default="",
+                    help="round counts at which to follow the server's "
+                         "change gap on the seeds' sound readings")
     ap.add_argument("--controls", default="")
     ap.add_argument("--faults", default="")
     ap.add_argument("--control-seeds", type=int, default=0,
@@ -143,21 +200,24 @@ def main(argv=None) -> int:
     bench_run.enable_cache(jax)
     bench_run.device_info(jax, cell.chips)
 
-    def emit(kind, seed, readings):
+    def emit(kind, seed, readings, **detail):
         print(json.dumps({"workload": cell.name, "kind": kind, "seed": seed,
-                          "readings": readings}), flush=True)
+                          "readings": readings, **detail}), flush=True)
 
-    n_control = args.control_seeds or len(seeds)
-    for i, seed in enumerate(seeds):
-        emit("program", seed, program_readings(cell, seed))
-        for mode in filter(None, args.controls.split(",")):
-            if i < n_control:
-                emit(f"control:{mode}", seed,
-                     control_readings(cell, seed, mode))
+    growth = [int(r) for r in filter(None, args.growth.split(","))]
+    for seed in seeds:
+        readings, detail = program_readings(cell, seed, growth=growth)
+        emit("program", seed, readings, **detail)
+    # each control and fault retraces the program once, for all its seeds
+    for mode in filter(None, args.controls.split(",")):
+        jax.clear_caches()
+        for seed in seeds[:args.control_seeds or len(seeds)]:
+            emit(f"control:{mode}", seed, control_readings(cell, seed, mode))
     for fault in filter(None, args.faults.split(",")):
+        jax.clear_caches()
         for seed in seeds[:args.fault_seeds]:
             emit(f"fault:{fault}", seed,
-                 program_readings(cell, seed, fault=fault))
+                 program_readings(cell, seed, fault=fault)[0])
     return 0
 
 

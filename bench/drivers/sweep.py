@@ -8,15 +8,23 @@ of trajectory-rounds it simulates.
 
 Correctness: every trajectory of the last window call is followed through
 all of its rounds by the plain reference (``bench.refs.paper_mlp``): the
-mean client loss of its first ``loss_rounds`` rounds to a relative gap,
-the norm of the server parameters' change over the call to a relative gap
-by the worst leaf, and the active-client count of every round exactly. ``run_sweep`` returns metrics only, so the server
-parameters are read from the program's batched runner as the window's
-calls leave them (:class:`Capture`).
+mean client loss of its first ``loss_rounds`` rounds to a relative gap
+(``loss_gap``), and the active-client count of every round exactly
+(``active_mismatch``). The norm of the server parameters' change is
+compared by the worst leaf (:func:`change_gap`) twice: over the first
+``loss_rounds`` rounds (``change_gap``), read from one more ``run_sweep``
+call of that length made after the window, whose rows must be the window
+call's first rounds exactly; and over the whole call (``call_change_gap``),
+where round-off between two float32 programs has grown over 50 rounds of
+SGD, as a guard against gross faults. ``run_sweep`` returns metrics only,
+so the server parameters are read from the program's batched runners as
+the calls leave them (:class:`Capture`).
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, List, Optional
+from unittest import mock
 
 import numpy as np
 
@@ -45,7 +53,7 @@ class Capture:
         return getattr(self.inner, name)
 
 
-def capture_runners() -> List[Capture]:
+def capture_runners() -> None:
     """Wrap every runner in the program's runner cache (once) and clear what
     they hold."""
     from repro.experiments import grid
@@ -56,7 +64,6 @@ def capture_runners() -> List[Capture]:
             cache[key] = Capture(runner)
     for runner in cache.values():
         runner.last = None
-    return list(cache.values())
 
 
 class Workload:
@@ -83,30 +90,33 @@ class Workload:
             n_train=sizes["n_train"], per_client=sizes["per_client"],
             alpha=sizes["alpha"], sigma0=sizes["sigma0"],
             delta=sizes["delta"], gamma=sizes["gamma"])
+        # the same grid over the rounds whose loss is compared
+        n = traffic["loss_rounds"]
+        self.early_spec = dataclasses.replace(self.spec, rounds=n,
+                                              eval_every=n)
         self.trajectories = (len(self.spec.algorithms)
                              * len(self.spec.hparam_points())
                              * len(self.spec.seeds))
         self.work_per_step = self.trajectories * self.spec.rounds
         self.first: Optional[List] = None
         self.last: Optional[List] = None
-        self.runners: List[Capture] = []
         self.failed = 0
 
     def _ctx(self):
         return self.jax.default_matmul_precision(self.precision)
 
-    def _call(self):
+    def _call(self, spec=None):
         from repro.experiments import run_sweep
 
         with self._ctx(), self.jax.profiler.TraceAnnotation(SPAN):
-            return run_sweep(self.spec)
+            return run_sweep(spec or self.spec)
 
     def prepare(self) -> None:
         """Compiles (or loads) and runs the grid once; that call's rows are
         what every later call must repeat. Later calls leave their server
         parameters with :class:`Capture`."""
         self.first = self.last = self._call()
-        self.runners = capture_runners()
+        capture_runners()
 
     def step(self) -> int:
         cells = self._call()
@@ -123,10 +133,15 @@ class Workload:
         it, so nothing is freed."""
 
     def server_of_last_call(self, cells) -> List[Dict[str, np.ndarray]]:
-        """The server parameters the last window call ended with, one
+        """The server parameters the last call ended with, one
         trajectory per row in the order of :meth:`trajectories_of`, after
-        checking that the runner's metrics are that call's rows."""
-        live = [r.last for r in self.runners if r.last is not None]
+        checking that it is the one runner call made since
+        :func:`capture_runners` and that its metrics are that call's
+        rows."""
+        from repro.experiments import grid
+
+        live = [r.last for r in grid._RUNNER_CACHE.values()
+                if isinstance(r, Capture) and r.last is not None]
         if len(live) != 1:
             raise RuntimeError(f"expected one runner call, found {len(live)}")
         states, out = live[0]
@@ -137,6 +152,19 @@ class Workload:
         server = {k: np.asarray(v) for k, v in states.server.items()}
         return [{k: v[b] for k, v in server.items()}
                 for b in range(len(rows))]
+
+    def call_servers(self, spec):
+        """One more call of ``spec``, outside the window: its rows and the
+        server parameters it ended with (a runner it builds is captured
+        too)."""
+        from repro.experiments import grid
+
+        capture_runners()
+        build = grid.make_batched_run_rounds
+        with mock.patch.object(grid, "make_batched_run_rounds",
+                               lambda *a, **kw: Capture(build(*a, **kw))):
+            cells = self._call(spec)
+        return cells, self.server_of_last_call(cells)
 
     # -- correctness -------------------------------------------------------
 
@@ -159,9 +187,12 @@ class Workload:
                     algo=cell.algo, lr=cell.hparams["lr"], seed=seed,
                     gamma=gamma)
 
-    def reference(self, cells, mode: str = "highest") -> List[Dict]:
+    def reference(self, cells, mode: str = "highest",
+                  keep=None) -> List[Dict]:
         """The reference's run of every trajectory of ``cells``, through all
-        of the call's rounds, in their order, at ``mode``."""
+        of the call's rounds, in their order, at ``mode``, with its server
+        parameters after each round count in ``keep`` (by default the
+        compared ones: ``loss_rounds`` and the call's)."""
         s, sz = self.spec, self.config["sizes"]
         data = ref.dataset(s.data_seed, dim=sz["dim"], classes=sz["classes"],
                            n_per_class=sz["n_per_class"],
@@ -171,35 +202,46 @@ class Workload:
         probs = {seed: ref.uplink_probs(
             seed, s.num_clients, sz["classes"], alpha=sz["alpha"],
             sigma0=sz["sigma0"], delta=sz["delta"]) for seed in s.seeds}
+        keep = keep or (self.early_spec.rounds, s.rounds)
         return [ref.follow(traj, self.proto(), data, idx, probs[traj.seed],
-                           s.rounds, mode)
+                           s.rounds, mode, keep)
                 for _, _, traj in self.trajectories_of(cells)]
 
     def rows(self, cells) -> List[Dict]:
-        """The program's rounds of every trajectory of the last call, in the
-        reference's layout."""
-        servers = self.server_of_last_call(cells)
+        """The program's rounds of every trajectory of the last window
+        call, in the reference's layout, with the server parameters of that
+        call and of one more over its first ``loss_rounds`` rounds, whose
+        rows must be the window call's first rounds exactly."""
+        call = self.server_of_last_call(cells)
+        n = self.early_spec.rounds
+        early_cells, early = self.call_servers(self.early_spec)
+        if not same_rounds(early_cells, cells, n):
+            raise RuntimeError(f"the call over {n} rounds does not repeat "
+                               f"the window call's first {n}")
         return [{"loss": cell.loss[j], "num_active": cell.num_active[j],
-                 "server": server}
-                for (cell, j, _), server in zip(self.trajectories_of(cells),
-                                                servers)]
+                 "servers": {n: e, self.spec.rounds: c}}
+                for (cell, j, _), e, c in zip(self.trajectories_of(cells),
+                                              early, call)]
 
     def compare(self, rows: List[Dict], refs: List[Dict]) -> Dict[str, float]:
         """Worst relative loss gap over the first ``loss_rounds`` rounds;
-        worst relative gap of a leaf's change norm (:func:`change_gap`);
-        the count of rounds whose active clients differ."""
-        n = self.traffic["loss_rounds"]
-        gap, change, active = 0.0, 0.0, 0
+        worst relative gap of a leaf's change norm (:func:`change_gap`)
+        over those rounds and over the whole call; the count of rounds
+        whose active clients differ."""
+        n, k = self.early_spec.rounds, self.spec.rounds
+        gap, early, call, active = 0.0, 0.0, 0.0, 0
         for p, r in zip(rows, refs):
             loss = np.asarray(p["loss"][:n], np.float64)
             gap = _worst(gap, float(np.max(np.abs(loss - r["loss"][:n])
                                            / np.abs(r["loss"][:n]))))
-            change = _worst(change, change_gap(p["server"], r["server"],
-                                               r["init"]))
+            early = _worst(early, change_gap(p["servers"][n],
+                                             r["servers"][n], r["init"]))
+            call = _worst(call, change_gap(p["servers"][k], r["servers"][k],
+                                           r["init"]))
             active += int(np.sum(np.asarray(p["num_active"])
                                  != r["num_active"]))
-        return {"loss_gap": gap, "change_gap": change,
-                "active_mismatch": float(active)}
+        return {"loss_gap": gap, "change_gap": early,
+                "call_change_gap": call, "active_mismatch": float(active)}
 
     def check(self) -> Dict[str, float]:
         return self.compare(self.rows(self.last), self.reference(self.last))
@@ -222,16 +264,16 @@ class Workload:
             local_steps=s.local_steps, batch=s.batch_size)
 
 
-def change_gap(program: Dict, reference: Dict, init: Dict) -> float:
-    """The worst leaf's gap between the program's and the reference's norm
-    of the change from ``init``, over the reference's norm of that leaf or
-    of the median leaf, whichever is larger. Leaves the reference moves by
+def leaf_gaps(program: Dict, reference: Dict, init: Dict) -> Dict[str, float]:
+    """Each leaf's gap between the program's and the reference's norm of
+    the change from ``init``, over the reference's norm of that leaf or of
+    the median leaf, whichever is larger. Leaves the reference moves by
     less than :data:`STILL` of the median leaf's are left out; where the
     reference's server did not move, the program's must not either."""
     ref_norm = {k: float(np.linalg.norm(np.asarray(reference[k], np.float64)
                                         - init[k])) for k in reference}
     median = float(np.median(list(ref_norm.values())))
-    worst = 0.0
+    gaps = {}
     for k, r in ref_norm.items():
         if r < STILL * median:
             continue
@@ -239,9 +281,17 @@ def change_gap(program: Dict, reference: Dict, init: Dict) -> float:
                                  - init[k]))
         scale = max(r, median)
         if scale == 0.0:    # nothing active: the server must stay put
-            worst = _worst(worst, 0.0 if p == 0.0 else float("inf"))
+            gaps[k] = 0.0 if p == 0.0 else float("inf")
         else:
-            worst = _worst(worst, abs(p - r) / scale)
+            gaps[k] = abs(p - r) / scale
+    return gaps
+
+
+def change_gap(program: Dict, reference: Dict, init: Dict) -> float:
+    """The worst leaf's gap of :func:`leaf_gaps` (0 where none counts)."""
+    worst = 0.0
+    for gap in leaf_gaps(program, reference, init).values():
+        worst = _worst(worst, gap)
     return worst
 
 
@@ -256,6 +306,15 @@ def _same(a, b) -> bool:
     return len(a) == len(b) and all(
         np.array_equal(getattr(x, f), getattr(y, f), equal_nan=True)
         for x, y in zip(a, b) for f in fields)
+
+
+def same_rounds(a, b, rounds: int) -> bool:
+    """Whether ``a``'s per-round rows are ``b``'s first ``rounds``,
+    exactly."""
+    return len(a) == len(b) and all(
+        np.array_equal(getattr(x, f), getattr(y, f)[:, :rounds],
+                       equal_nan=True)
+        for x, y in zip(a, b) for f in ("loss", "num_active"))
 
 
 def _finite(cells) -> bool:

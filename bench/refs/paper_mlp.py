@@ -23,7 +23,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from typing import Dict, List
+from typing import Dict, List, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -224,10 +224,12 @@ def _dense_round(carry, round_idx, data, idx, p_base, lr, gamma, data_key,
 
 
 def follow(traj: Trajectory, proto: Protocol, data, idx, p_base,
-           rounds: int, mode: str = "highest") -> Dict[str, np.ndarray]:
+           rounds: int, mode: str = "highest",
+           keep: Sequence[int] = ()) -> Dict[str, np.ndarray]:
     """The trajectory's first ``rounds`` rounds: per-round mean client loss
     and active-client count, the server parameters it starts from
-    (``init``) and ends with (``server``)."""
+    (``init``) and those it holds after each round count in ``keep``
+    (``servers``, by count)."""
     init = init_mlp(traj.seed, dim=proto.dim, hidden=proto.hidden,
                     classes=proto.classes)
     # the state key is the second half of PRNGKey(seed + 2)'s split (the
@@ -241,13 +243,16 @@ def follow(traj: Trajectory, proto: Protocol, data, idx, p_base,
     step = partial(_dense_round, algo=traj.algo, proto=proto, mode=mode)
     carry = (init, _tile(init, proto.m), key)
     out: Dict[str, list] = {}
+    servers = {}
     for r in range(rounds):
         carry, mets = step(carry, jnp.int32(r), data, idx, p_base, lr, gamma,
                            data_key)
         for k, v in mets.items():
             out.setdefault(k, []).append(np.asarray(v))
+        if r + 1 in keep:
+            servers[r + 1] = {k: np.asarray(v, np.float32)
+                              for k, v in carry[0].items()}
     res = {k: np.stack(v) for k, v in out.items()}
     res["init"] = {k: np.asarray(v, np.float64) for k, v in init.items()}
-    res["server"] = {k: np.asarray(v, np.float32)
-                     for k, v in carry[0].items()}
+    res["servers"] = servers
     return res
