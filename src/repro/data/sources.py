@@ -28,6 +28,59 @@ import jax.numpy as jnp
 Pytree = Any
 
 
+def draw_indices(key, client_idx, clients, local_steps: int,
+                 batch_size: int):
+    """Dataset indices of one round's draws, ``[C, s, b]``:
+    ``client_idx[clients[c], pick[c, s, b]]`` with ``pick`` drawn uniformly
+    from ``[0, per_client)``; ``clients=None`` means every client in order
+    (the dense round), else a ``[C]`` cohort.
+
+    Each draw is resolved inside its client's row, not by an element gather
+    over the ``[m, per_client]`` table: its position is compared with the
+    row's iota and the one match summed. That is exact integer work which
+    fuses into a reduction, so the ``[C, s, b, per_client]`` operand is
+    never materialised and the index table is only row-gathered (for a
+    cohort). It costs ``per_client`` integer operations a draw: at the
+    row widths the tasks use (64 and below) far less than an element
+    gather costs a draw on the TPU.
+    """
+    per_client = client_idx.shape[1]
+    rows = client_idx if clients is None else client_idx[clients]
+    pick = jax.random.randint(
+        key, (rows.shape[0], local_steps, batch_size), 0, per_client)
+    hit = pick[..., None] == jnp.arange(per_client, dtype=pick.dtype)
+    return jnp.sum(jnp.where(hit, rows[:, None, None, :], 0), axis=-1,
+                   dtype=client_idx.dtype)
+
+
+def example_sampler(x, y, *, local_steps: int, batch_size: int):
+    """``take(key, client_idx, clients) -> {"x", "y"}``: one round's
+    batches, drawn by ``draw_indices`` and fetched by ONE row gather.
+
+    ``x [n, ...]`` (32-bit) and ``y [n]`` are packed once into an
+    ``[n, f + 1]`` int32 table: the features' bits, then the label. Both
+    come back bit-exactly. The label is not stored as a float32 bit
+    pattern: small integers are float32 denormals, which the TPU may flush
+    to zero.
+    """
+    n, shape = x.shape[0], x.shape[1:]
+    # built where the source factory is called, outside the round engine's
+    # sampling scope: name it so that its device time still counts there
+    with jax.named_scope("fed.sample"):
+        table = jnp.concatenate(
+            [jax.lax.bitcast_convert_type(x.reshape(n, -1), jnp.int32),
+             y.astype(jnp.int32)[:, None]], axis=1)
+
+    def take(key, client_idx, clients):
+        rows = table[draw_indices(key, client_idx, clients, local_steps,
+                                  batch_size)]
+        feats = jax.lax.bitcast_convert_type(rows[..., :-1], x.dtype)
+        return {"x": feats.reshape(rows.shape[:-1] + shape),
+                "y": rows[..., -1].astype(y.dtype)}
+
+    return take
+
+
 @dataclass(frozen=True)
 class DataSource:
     init: Callable[..., Any]      # (key) -> ds_state
@@ -49,26 +102,18 @@ def classification_source(x, y, client_idx, *, local_steps: int,
     from every client's shard (same distribution as the host-side
     ``federated_classification_batches``).
     """
-    x = jnp.asarray(x)
-    y = jnp.asarray(y)
     client_idx = jnp.asarray(client_idx)
-    m, per_client = client_idx.shape
+    take = example_sampler(jnp.asarray(x), jnp.asarray(y),
+                           local_steps=local_steps, batch_size=batch_size)
 
     def init(key):
         return ()
 
     def sample(ds_state, t, key):
-        pick = jax.random.randint(
-            key, (m, local_steps, batch_size), 0, per_client)
-        sel = client_idx[jnp.arange(m)[:, None, None], pick]
-        return {"x": x[sel], "y": y[sel]}, ds_state
+        return take(key, client_idx, None), ds_state
 
     def sample_cohort(ds_state, t, key, cohort):
-        C = cohort.shape[0]
-        pick = jax.random.randint(
-            key, (C, local_steps, batch_size), 0, per_client)
-        sel = client_idx[cohort[:, None, None], pick]
-        return {"x": x[sel], "y": y[sel]}, ds_state
+        return take(key, client_idx, cohort), ds_state
 
     return DataSource(init, sample, "classification", sample_cohort)
 
@@ -89,27 +134,22 @@ def traced_classification_source(shared, *, local_steps: int,
     batched-runner protocol; the key is accepted for signature symmetry and
     unused). ``sample`` draws the same indices as ``classification_source`` —
     given equal arrays the two sources produce bit-for-bit equal batches.
+    The packed example table (``example_sampler``) is built when the factory
+    is called: the sweep engine calls it once per compiled call, outside the
+    round scan.
     """
+
+    take = example_sampler(shared["x"], shared["y"], local_steps=local_steps,
+                           batch_size=batch_size)
 
     def init(key, data):
         return data
 
     def sample(ds_state, t, key):
-        client_idx = ds_state["idx"]
-        m, per_client = client_idx.shape
-        pick = jax.random.randint(
-            key, (m, local_steps, batch_size), 0, per_client)
-        sel = client_idx[jnp.arange(m)[:, None, None], pick]
-        return {"x": shared["x"][sel], "y": shared["y"][sel]}, ds_state
+        return take(key, ds_state["idx"], None), ds_state
 
     def sample_cohort(ds_state, t, key, cohort):
-        client_idx = ds_state["idx"]
-        per_client = client_idx.shape[1]
-        C = cohort.shape[0]
-        pick = jax.random.randint(
-            key, (C, local_steps, batch_size), 0, per_client)
-        sel = client_idx[cohort[:, None, None], pick]
-        return {"x": shared["x"][sel], "y": shared["y"][sel]}, ds_state
+        return take(key, ds_state["idx"], cohort), ds_state
 
     return DataSource(init, sample, "classification_traced", sample_cohort)
 
@@ -136,20 +176,13 @@ def traced_lm_source(shared, *, local_steps: int,
         return {"tokens": seqs[..., :-1], "labels": seqs[..., 1:]}
 
     def sample(ds_state, t, key):
-        client_idx = ds_state["idx"]
-        m, per_client = client_idx.shape
-        pick = jax.random.randint(
-            key, (m, local_steps, batch_size), 0, per_client)
-        sel = client_idx[jnp.arange(m)[:, None, None], pick]
+        sel = draw_indices(key, ds_state["idx"], None, local_steps,
+                           batch_size)
         return _slice(shared["toks"][sel]), ds_state
 
     def sample_cohort(ds_state, t, key, cohort):
-        client_idx = ds_state["idx"]
-        per_client = client_idx.shape[1]
-        C = cohort.shape[0]
-        pick = jax.random.randint(
-            key, (C, local_steps, batch_size), 0, per_client)
-        sel = client_idx[cohort[:, None, None], pick]
+        sel = draw_indices(key, ds_state["idx"], cohort, local_steps,
+                           batch_size)
         return _slice(shared["toks"][sel]), ds_state
 
     return DataSource(init, sample, "lm_traced", sample_cohort)

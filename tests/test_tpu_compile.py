@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.data.sources import example_sampler
 from repro.kernels.dispatch import fused_agg_pytree
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.masked_agg import fused_masked_agg
@@ -113,3 +114,23 @@ def test_flash_attention_forward_and_grad_lower(one_chip, t):
     # forward kernel live next to the backward pass
     _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, k, v,
              kernel="flash_attention")
+
+
+def test_in_row_sampling_is_not_materialised(one_chip):
+    """mlp-family's round of draws (B=16 trajectories x m=100 clients x 5
+    steps x 32) resolved inside index rows 1,024 wide, 16 times
+    mlp-family's: the chip's compiler fuses the compare-and-select into its
+    reduction, so the program's scratch stays under half of the
+    ``[B, m, s, b, per_client]`` int32 operand it would take materialised."""
+    B, m, s, b, n, d, per_client = 16, 100, 5, 32, 5000, 32, 1024
+
+    def sample(keys, idx, x, y):
+        take = example_sampler(x, y, local_steps=s, batch_size=b)
+        return jax.vmap(lambda k, ix: take(k, ix, None))(keys, idx)
+
+    compiled = jax.jit(sample).lower(
+        _spec(one_chip, (B, 2), jnp.uint32),
+        _spec(one_chip, (B, m, per_client), jnp.int32),
+        _spec(one_chip, (n, d)), _spec(one_chip, (n,), jnp.int32)).compile()
+    operand = B * m * s * b * per_client * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < operand // 2
