@@ -291,6 +291,10 @@ def phase_lm_trainer(jax):
     cfg = tr.cfg
     check((cfg.num_layers, cfg.d_model, cfg.vocab_size, cfg.dtype)
           == (30, 576, 49152, "bfloat16"), f"not the full config: {cfg}")
+    # bfloat16 is the compute dtype; the federated state is float32 masters
+    held = {str(x.dtype) for x in jax.tree.leaves(
+        (tr.state.server, tr.state.clients))}
+    check(held == {"float32"}, f"federated state is not float32: {held}")
 
     # one client's gradient: compiled flash kernel vs the XLA attention
     batches, _ = tr.source.sample(tr.ds_state, 0, jax.random.PRNGKey(7))

@@ -59,20 +59,30 @@ def _bmask(mask, leaf):
     return mask.reshape(mask.shape + (1,) * (leaf.ndim - 1)).astype(leaf.dtype)
 
 
+def _f32(x):
+    return x.astype(jnp.float32)
+
+
 def masked_mean(xs: Pytree, active) -> Pytree:
     """Mean over the client axis restricted to active clients.
 
     = (1/|A|) sum_{i in A} x_i ; falls back to 0 when A is empty (callers
     guard with ``any_active``). This is the paper's server aggregation and
     the op kernelized in ``repro.kernels.masked_agg``.
+
+    Summed, divided and returned in float32 whatever the leaves' dtype: a
+    mean of a few clients' bfloat16 parameters rounded back to the
+    bfloat16 grid would move updates of about one ulp by as much as the
+    updates themselves.
     """
     denom = jnp.maximum(active.sum().astype(jnp.float32), 1.0)
     return jax.tree.map(
-        lambda x: (x * _bmask(active, x)).sum(0) / denom.astype(x.dtype), xs)
+        lambda x: (_f32(x) * _bmask(active, _f32(x))).sum(0) / denom, xs)
 
 
 def weighted_sum(xs: Pytree, w) -> Pytree:
-    return jax.tree.map(lambda x: (x * _bmask(w, x)).sum(0), xs)
+    """``sum_i w_i x_i`` over the client axis, in float32."""
+    return jax.tree.map(lambda x: (_f32(x) * _bmask(w, _f32(x))).sum(0), xs)
 
 
 def bcast_where(active, new: Pytree, old: Pytree) -> Pytree:
@@ -138,7 +148,7 @@ def _agg_fedpbc(algo, server, clients, x_star, active, p_t, t):
     any_active = active.any()
     agg = masked_mean(x_star, active)
     new_server = jax.tree.map(
-        lambda a, s: jnp.where(any_active, a, s), agg, server)
+        lambda a, s: jnp.where(any_active, a.astype(s.dtype), s), agg, server)
     # postponed broadcast: only active clients receive the new global model
     new_clients = bcast_where(active, new_server, x_star)
     return algo, new_server, new_clients
@@ -148,7 +158,8 @@ def _agg_fedavg(algo, server, clients, x_star, active, p_t, t):
     """Vanilla FedAvg: average active clients; broadcast to everyone."""
     any_active = active.any()
     agg = masked_mean(x_star, active)
-    new_server = jax.tree.map(lambda a, s: jnp.where(any_active, a, s), agg, server)
+    new_server = jax.tree.map(
+        lambda a, s: jnp.where(any_active, a.astype(s.dtype), s), agg, server)
     m = active.shape[0]
     return algo, new_server, _tile(new_server, m)
 
@@ -220,7 +231,8 @@ def _make_agg_f3ast(beta: float, cap: int):
         selected = active & (rank < cap)
         any_sel = selected.any()
         agg = masked_mean(x_star, selected)
-        new_server = jax.tree.map(lambda a, s: jnp.where(any_sel, a, s), agg, server)
+        new_server = jax.tree.map(
+            lambda a, s: jnp.where(any_sel, a.astype(s.dtype), s), agg, server)
         m = active.shape[0]
         return dataclasses.replace(algo, lam=lam), new_server, _tile(new_server, m)
 

@@ -164,13 +164,19 @@ class SweepSpec:
     # (("fedau_K", 100), ("period", 20)))
     fed_overrides: Tuple[Tuple[str, Any], ...] = ()
     # workload: "classification" (the paper's Gaussian/MLP stand-in) or "lm"
-    # (reduced-config transformer next-token task, repro.experiments.tasks
+    # (transformer next-token task, repro.experiments.tasks
     # .make_traced_lm_task). For "lm" the lm_* knobs shape the model/corpus
     # (classes doubles as the number of corpus styles, per_client /
     # local_steps / batch_size keep their meaning), and dim/hidden/
     # n_per_class/n_train are ignored.
     task: str = "classification"
     lm_arch: str = "smollm-135m"
+    # True: the CPU-test form, lm_arch cut to lm_d_model x lm_layers in
+    # float32; False: lm_arch at its published widths and depth, computing
+    # in its config's dtype with each layer recomputed in the backward pass
+    # (lm_d_model / lm_layers ignored). Federated state holds float32
+    # master weights either way.
+    lm_reduced: bool = True
     lm_d_model: int = 64
     lm_layers: int = 2
     lm_seq: int = 32                # training context length
@@ -343,7 +349,7 @@ def _task_key(spec: SweepSpec) -> tuple:
             spec.hidden, spec.n_per_class, spec.n_train,
             spec.per_client, spec.local_steps, spec.batch_size,
             spec.task, spec.lm_arch, spec.lm_d_model, spec.lm_layers,
-            spec.lm_seq, spec.lm_n_seqs, spec.lm_n_test)
+            spec.lm_seq, spec.lm_n_seqs, spec.lm_n_test, spec.lm_reduced)
 
 
 def get_task(spec: SweepSpec) -> ClassificationTask:
@@ -374,7 +380,8 @@ def get_traced_task(spec: SweepSpec) -> TracedClassificationTask:
                 layers=spec.lm_layers, seq_len=spec.lm_seq,
                 classes=spec.classes, n_seqs=spec.lm_n_seqs,
                 n_test=spec.lm_n_test, per_client=spec.per_client,
-                local_steps=spec.local_steps, batch_size=spec.batch_size)
+                local_steps=spec.local_steps, batch_size=spec.batch_size,
+                reduced_widths=spec.lm_reduced)
         else:
             _TRACED_TASK_CACHE[key] = make_traced_classification_task(
                 data_seed=spec.data_seed, num_clients=spec.num_clients,

@@ -222,12 +222,14 @@ def make_batched_run_rounds(loss_fn: Callable, algorithm,
         the link/optimizer state ride the carry, k chained segments are
         bit-for-bit equal to one uninterrupted ``k * num_rounds`` program
         with the same eval cadence (``tests/test_search.py``).
-      donate_carry: in ``carry_out`` mode, donate the incoming ``(st, ds)``
-        carry buffers to the scan stage so each segment updates in place
-        instead of doubling the [B]-state footprint. Defaults to backend !=
-        "cpu" — the same gate as ``make_run_rounds`` (CPU ignores donation
-        noisily). After ``run.step(carry, ...)`` the passed carry is dead on
-        donating backends; rebind, never reuse.
+      donate_carry: donate the incoming carry to the scan stage so it
+        updates in place instead of doubling the [B]-state footprint: the
+        ``(st, ds)`` carry in ``carry_out`` mode, the FedState alone in the
+        one-shot mode (its ``ds`` may be the batch's own partition table,
+        which later calls reuse). Defaults to backend != "cpu" — the same
+        gate as ``make_run_rounds`` (CPU ignores donation noisily). After
+        ``run.step(carry, ...)`` the passed carry is dead on donating
+        backends; rebind, never reuse.
 
     Returns ``run(batch: CellBatch) -> (states, out)`` where ``states`` is a
     [B]-batched ``FedState`` and ``out["metrics"]`` maps each metric key to a
@@ -392,11 +394,13 @@ def make_batched_run_rounds(loss_fn: Callable, algorithm,
     init_batch = jax.jit(jax.vmap(init_point, in_axes=(0, 0, 0, 0, None, 0),
                                   spmd_axis_name="batch"))
     # carry_out segments update the [B]-state in place (donated (st, ds))
-    # so chaining rungs never doubles the state footprint; the historical
-    # one-shot mode keeps its undonated signature untouched
+    # so chaining rungs never doubles the state footprint; the one-shot
+    # mode donates the FedState its own init stage just made, so a call
+    # holds one copy of the client stacks, not an input and an output
+    # (a full-width LM's [B, m, P] float32 clients fill a chip otherwise)
     if donate_carry is None:
         donate_carry = jax.default_backend() != "cpu"  # CPU ignores donation
-    donate = (0, 1) if (carry_out and donate_carry) else ()
+    donate = ((0, 1) if carry_out else (0,)) if donate_carry else ()
     scan_batch = jax.jit(jax.vmap(scan_point,
                                   in_axes=(0, 0, 0, 0, 0, None, 0),
                                   spmd_axis_name="batch"),

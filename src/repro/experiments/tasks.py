@@ -215,28 +215,73 @@ def _styled_corpus(rng, *, n, seq_len, vocab, classes):
     return toks.astype(np.int32), styles
 
 
+# Bytes of float32 logits one eval chunk may hold, per trajectory: the
+# next-token eval runs the corpus through the model this many sequences'
+# worth at a time (at least one), so a published vocabulary never puts a
+# whole corpus's [n, T, V] logits on the device at once.
+EVAL_LOGITS_BYTES = 1 << 28
+
+
+def eval_chunk(n: int, seq_len: int, vocab: int) -> int:
+    """Sequences per next-token eval chunk: the largest divisor of ``n``
+    whose float32 logits fit :data:`EVAL_LOGITS_BYTES`."""
+    fit = max(1, EVAL_LOGITS_BYTES // (seq_len * vocab * 4))
+    return max(c for c in range(1, min(fit, n) + 1) if n % c == 0)
+
+
+def next_token_accuracy(forward, seqs, chunk: int):
+    """Share of ``seqs[:, 1:]`` that ``forward(tokens) -> logits`` predicts
+    by argmax, ``chunk`` sequences at a time (one pass where ``chunk``
+    covers them all). Exact whatever the chunk: correct predictions are
+    counted, then divided once."""
+    n = seqs.shape[0]
+    if chunk >= n:
+        return (jnp.argmax(forward(seqs[:, :-1]), -1) == seqs[:, 1:]).mean()
+
+    def hits(count, block):
+        right = jnp.argmax(forward(block[:, :-1]), -1) == block[:, 1:]
+        return count + right.sum(dtype=jnp.int32), None
+
+    count, _ = jax.lax.scan(hits, jnp.int32(0),
+                            seqs.reshape((n // chunk, chunk) + seqs.shape[1:]))
+    return count.astype(jnp.float32) / (n * (seqs.shape[1] - 1))
+
+
 def make_traced_lm_task(*, data_seed=0, num_clients=8, arch="smollm-135m",
                         d_model=64, layers=2, seq_len=32, classes=4,
                         n_seqs=256, n_test=64, per_client=16, local_steps=2,
-                        batch_size=2) -> LMTask:
-    """Reduced-config transformer LM as a first-class sweep workload.
+                        batch_size=2, reduced_widths=True) -> LMTask:
+    """Transformer LM as a first-class sweep workload.
 
-    The model is ``reduced(get_config(arch), d_model, layers)`` forced to
-    float32 (the sweep engine's bitwise contracts assume f32 accumulation);
-    the corpus is a synthetic styled token set, Dirichlet-partitioned over
+    ``reduced_widths`` (the CPU-test form) builds ``reduced(get_config(arch),
+    d_model, layers)`` forced to float32 (the sweep engine's bitwise
+    contracts assume f32 accumulation); without it the model is ``arch`` at
+    its published widths and depth, computing in its config's dtype.
+    Either way the federated state holds float32 master weights
+    (``repro.models.model.master_config``) and each client's loss casts
+    them to the compute dtype (``compute_params``), so local SGD,
+    aggregation and broadcast run in float32. At published widths each
+    layer is recomputed in the backward pass: a chip holds B x m float32
+    client masters, not their saved activations too.
+
+    The corpus is a synthetic styled token set, Dirichlet-partitioned over
     per-sequence style labels exactly like the classification task is over
     class labels — so the non-IID severity knob ``alpha`` means the same
     thing. Everything is traced: the corpus rides ``shared`` ({"toks"
     [n, T+1], "toks_t" [n_test, T+1]}), the partition rides ``ds_state``,
-    evals take ``(params, shared)`` and report next-token accuracy.
+    evals take ``(params, shared)`` and report next-token accuracy, run in
+    chunks of sequences (:func:`eval_chunk`).
     """
     import dataclasses as _dc
 
     from repro.configs import get_config, reduced
     from repro.models import model as lm
 
-    cfg = _dc.replace(reduced(get_config(arch), d_model=d_model,
-                              layers=layers), dtype="float32")
+    cfg = get_config(arch)
+    if reduced_widths:
+        cfg = _dc.replace(reduced(cfg, d_model=d_model, layers=layers),
+                          dtype="float32")
+    master = lm.master_config(cfg)
     rng = np.random.default_rng(data_seed)
     toks, styles = _styled_corpus(rng, n=n_seqs, seq_len=seq_len,
                                   vocab=cfg.vocab_size, classes=classes)
@@ -244,6 +289,7 @@ def make_traced_lm_task(*, data_seed=0, num_clients=8, arch="smollm-135m",
                                vocab=cfg.vocab_size, classes=classes)
     shared = {"toks": jnp.asarray(toks), "toks_t": jnp.asarray(toks_t)}
     ce_chunk = min(512, seq_len)
+    remat = not reduced_widths
 
     def partition(alpha: float) -> np.ndarray:
         prng = np.random.default_rng(data_seed)
@@ -252,25 +298,28 @@ def make_traced_lm_task(*, data_seed=0, num_clients=8, arch="smollm-135m",
         return idx
 
     def lm_loss(params, batch):
-        return lm.loss_fn(params, cfg, batch, remat=False, ce_chunk=ce_chunk)
+        return lm.loss_fn(params, cfg, batch, remat=remat, ce_chunk=ce_chunk)
 
-    def next_token_accuracy(params, seqs):
-        logits, _ = lm.forward(params, cfg, seqs[:, :-1])
-        return (jnp.argmax(logits, -1) == seqs[:, 1:]).mean()
+    def accuracy(params, seqs):
+        return next_token_accuracy(
+            lambda tokens: lm.forward(params, cfg, tokens)[0], seqs,
+            eval_chunk(seqs.shape[0], seq_len, cfg.vocab_size))
 
     return LMTask(
         loss_fn=lm_loss,
-        init_params=lambda key: lm.init_params(key, cfg),
+        init_params=lambda key: lm.init_params(key, master),
         source_factory=lambda sh: traced_lm_source(
             sh, local_steps=local_steps, batch_size=batch_size),
-        eval_test=lambda params, sh: next_token_accuracy(params, sh["toks_t"]),
-        eval_train=lambda params, sh: next_token_accuracy(params, sh["toks"]),
+        eval_test=lambda params, sh: accuracy(params, sh["toks_t"]),
+        eval_train=lambda params, sh: accuracy(params, sh["toks"]),
         partition=partition,
         shared=shared,
         meta={"dataset": "styled-lm", "data_seed": data_seed, "arch": arch,
-              "d_model": d_model, "layers": layers, "seq_len": seq_len,
-              "classes": classes, "vocab": cfg.vocab_size,
+              "d_model": cfg.d_model, "layers": cfg.num_layers,
+              "seq_len": seq_len, "classes": classes,
+              "vocab": cfg.vocab_size, "dtype": cfg.dtype,
               "n_train": n_seqs, "n_test": n_test,
               "num_clients": num_clients, "per_client": per_client,
-              "local_steps": local_steps, "batch_size": batch_size},
+              "local_steps": local_steps, "batch_size": batch_size,
+              "param_count": cfg.param_count()},
     )

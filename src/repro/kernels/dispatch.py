@@ -39,9 +39,9 @@ differently at one-ulp level, see ``tests/test_kernels.py``):
 ``interpret``   fp32 leaves: bitwise on CPU (a family sweep with
                 ``use_kernel=True`` equals the XLA-path program per
                 trajectory, pinned by ``tests/test_kernel_sweep.py``);
-                bf16 leaves: the kernel accumulates in fp32 where the XLA
-                path computes in bf16 — differences up to ~1e-2 * magnitude
-                (bf16 epsilon).
+                bf16 leaves: both accumulate in fp32 (the engine's
+                federated state is fp32 masters anyway), equal up to the
+                order of the fp32 sums.
 ``xla``         identical math to the kernel (fp32 accumulation): bitwise
                 vs ``interpret`` on every platform.
 ``compiled``    allclose within 1e-6 (fp32) / 2e-2 (bf16): accelerator

@@ -6,8 +6,10 @@
 Runs the FedPBC round engine over the selected architecture on the local
 devices: ``--reduced`` (the default) cuts the model to the CPU-sized
 ``reduced()`` variant in float32, ``--full`` keeps every published width
-in the config's own dtype — e.g. the 30-layer, 135M-parameter smollm-135m
-in bfloat16 on one TPU v5e chip (``chip_smoke.py`` runs that path).
+and computes in the config's own dtype — e.g. the 30-layer, 135M-parameter
+smollm-135m in bfloat16 on one TPU v5e chip (``chip_smoke.py`` runs that
+path). Either way the federated state holds float32 master weights, which
+each client's loss casts to the compute dtype, as the sweep engine does.
 
 Rounds execute on the scanned engine (``repro.core.make_run_rounds``): token
 batches are sampled on device by ``repro.data.lm_source`` and every
@@ -78,7 +80,7 @@ def build(args: argparse.Namespace) -> Trainer:
         make_run_rounds,
     )
     from repro.data import lm_source
-    from repro.models.model import init_params, loss_fn
+    from repro.models.model import init_params, loss_fn, master_config
     from repro.optim import paper_decay, sgd
 
     cfg = get_config(args.arch)
@@ -98,8 +100,11 @@ def build(args: argparse.Namespace) -> Trainer:
     link = make_link_process(jnp.asarray(p), fed)
     opt = sgd(paper_decay(args.lr))
 
+    # at published widths every layer is recomputed in the backward pass:
+    # the float32 client masters leave no room for the clients' saved
+    # activations (8 clients of smollm-135m need 16.0 GiB without it)
     def loss(params, batch):
-        return loss_fn(params, cfg, batch, remat=False)
+        return loss_fn(params, cfg, batch, remat=not args.reduced)
 
     if cfg.family == "vlm":
         memory_shape = (args.batch, cfg.num_image_tokens, cfg.d_model)
@@ -112,7 +117,8 @@ def build(args: argparse.Namespace) -> Trainer:
                        memory_shape=memory_shape)
 
     run_rounds = make_run_rounds(loss, opt, algo, link, fed, source)
-    params = init_params(jax.random.PRNGKey(args.seed + 1), cfg)
+    params = init_params(jax.random.PRNGKey(args.seed + 1),
+                         master_config(cfg))
     st = init_fed_state(jax.random.PRNGKey(args.seed + 2), params, fed,
                         algo, link, opt)
     ds_state = source.init(jax.random.PRNGKey(args.seed + 3))

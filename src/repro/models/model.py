@@ -13,6 +13,8 @@ Design:
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
 import math
 from functools import partial
 
@@ -146,6 +148,34 @@ def init_params(key, cfg: ModelConfig):
     return params
 
 
+def master_config(cfg: ModelConfig) -> ModelConfig:
+    """``cfg`` with float32 parameters: what ``init_params`` of it gives are
+    the float32 master weights that federated state holds. Every forward
+    pass below computes in ``cfg.dtype`` from a cast of them
+    (:func:`compute_params`)."""
+    return dataclasses.replace(cfg, dtype="float32")
+
+
+@functools.lru_cache(maxsize=None)
+def _compute_dtypes(cfg: ModelConfig):
+    return jax.tree.map(lambda s: s.dtype, jax.eval_shape(
+        partial(init_params, cfg=cfg), jax.random.PRNGKey(0)))
+
+
+def compute_params(params, cfg: ModelConfig):
+    """``params`` in the dtypes ``init_params(key, cfg)`` gives each leaf:
+    the compute copy of float32 master weights (named ``fed.cast`` in the
+    compiled program), differentiable back to the masters. Params already
+    in those dtypes pass through untouched, so a program that holds them
+    in ``cfg.dtype`` is unchanged."""
+    dtypes = _compute_dtypes(cfg)
+    if all(x.dtype == d for x, d in zip(jax.tree.leaves(params),
+                                         jax.tree.leaves(dtypes))):
+        return params
+    with jax.named_scope("fed.cast"):
+        return jax.tree.map(lambda x, d: x.astype(d), params, dtypes)
+
+
 # ---------------------------------------------------------------------------
 # Forward (train / prefill)
 # ---------------------------------------------------------------------------
@@ -162,8 +192,9 @@ def _self_attn_block(p, x, cfg, kind, positions):
     cos, sin = rope_angles(positions, hd, a.rope_theta)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
-    o = attention(q, k, v, kind=kind, window=a.window,
-                  logit_softcap=a.logit_softcap)
+    with jax.named_scope("lm.attn"):
+        o = attention(q, k, v, kind=kind, window=a.window,
+                      logit_softcap=a.logit_softcap)
     return x + o.reshape(b, t, -1) @ p["attn"]["wo"]
 
 
@@ -185,7 +216,9 @@ def _ffn_block(p, x, cfg):
     if "moe" in p:
         out, aux = moe_apply(p["moe"], h, cfg)
         return x + out, aux
-    return x + mlp_apply(p["ffn"], h, cfg.gated_mlp), jnp.zeros((), jnp.float32)
+    with jax.named_scope("lm.mlp"):
+        out = mlp_apply(p["ffn"], h, cfg.gated_mlp)
+    return x + out, jnp.zeros((), jnp.float32)
 
 
 def _apply_layer(p, x, cfg, layer_idx, positions, memory):
@@ -227,7 +260,10 @@ def forward(params, cfg: ModelConfig, tokens, *, memory=None, remat=False):
     embeddings [B, F, D] (audio) — the stubbed modality frontends.
     ``remat``: checkpoint each period (training path) so the scan saves only
     the residual carries, not per-layer attention/FFN intermediates.
+    Float32 master ``params`` are cast to ``cfg.dtype`` first
+    (:func:`compute_params`).
     """
+    params = compute_params(params, cfg)
     x = params["embed"][tokens].astype(dtype_of(cfg))
     positions = jnp.arange(tokens.shape[1])
     if cfg.family == "vlm":
@@ -250,13 +286,15 @@ def forward(params, cfg: ModelConfig, tokens, *, memory=None, remat=False):
                                params["blocks"], unroll=scan_unroll())
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params.get("lm_head", None)
-    logits = (x @ head) if head is not None else (x @ params["embed"].T)
-    logits = softcap(logits.astype(jnp.float32), cfg.final_softcap)
+    with jax.named_scope("lm.head"):
+        logits = (x @ head) if head is not None else (x @ params["embed"].T)
+        logits = softcap(logits.astype(jnp.float32), cfg.final_softcap)
     return logits, aux
 
 
 def hidden_forward(params, cfg: ModelConfig, tokens, *, memory=None, remat=False):
     """Like ``forward`` but stops before the LM head: returns (hidden, aux)."""
+    params = compute_params(params, cfg)
     x = params["embed"][tokens].astype(dtype_of(cfg))
     positions = jnp.arange(tokens.shape[1])
     if cfg.family == "vlm":
@@ -286,11 +324,22 @@ def loss_fn(params, cfg: ModelConfig, batch, *, remat=True, ce_chunk=512):
     Cross-entropy is computed in token chunks under jax.checkpoint so the
     [B, T, V] logits tensor (tens of GB at 128k-256k vocab) never fully
     materializes — only one [B, ce_chunk, V] chunk is live at a time.
+    Float32 master ``params`` are cast to ``cfg.dtype`` here, so the
+    gradient comes back in float32.
     """
+    params = compute_params(params, cfg)
     hidden, aux = hidden_forward(params, cfg, batch["tokens"],
                                  memory=batch.get("memory"), remat=remat)
-    head = params["lm_head"] if "lm_head" in params else params["embed"].T
-    labels = batch["labels"]
+    with jax.named_scope("lm.head"):
+        head = params["lm_head"] if "lm_head" in params \
+            else params["embed"].T
+        ce = _chunked_ce(hidden, head, batch["labels"], cfg, ce_chunk)
+    return ce + 0.01 * aux
+
+
+def _chunked_ce(hidden, head, labels, cfg: ModelConfig, ce_chunk: int):
+    """Mean next-token cross-entropy of ``hidden @ head`` against
+    ``labels``, ``ce_chunk`` positions at a time (see :func:`loss_fn`)."""
     b, t, d = hidden.shape
 
     def chunk_ce(h_chunk, y_chunk):
@@ -315,17 +364,14 @@ def loss_fn(params, cfg: ModelConfig, batch, *, remat=True, ce_chunk=512):
         return tot + chunk_ce(h_, y_), None
 
     if pad:
-        valid = jnp.arange(n * ce_chunk) < t
         # simplest correct handling: compute full-seq in one chunk when padded
         logits = softcap((hidden @ head).astype(jnp.float32), cfg.final_softcap)
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-        ce = (lse - gold).mean()
-        return ce + 0.01 * aux
+        return (lse - gold).mean()
     tot, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (hc, yc),
                           unroll=scan_unroll())
-    ce = tot / (b * t)
-    return ce + 0.01 * aux
+    return tot / (b * t)
 
 
 # ---------------------------------------------------------------------------
@@ -407,6 +453,7 @@ def _decode_attn_layer(p, x, cfg, kind, cache, pos):
 def decode_step(params, cfg: ModelConfig, token, cache, pos, *, memory=None):
     """token [B, 1] int32; cache from make_cache; pos scalar int32 (= tokens
     already in cache). Returns (logits [B, 1, V], new_cache)."""
+    params = compute_params(params, cfg)
     x = params["embed"][token].astype(dtype_of(cfg))
     if cfg.family == "vlm":
         memory = memory @ params["image_proj"]

@@ -30,6 +30,12 @@ import jax
 
 STAGES = ("fed.sample", "fed.link", "fed.broadcast", "fed.local_train",
           "fed.aggregate", "fed.eval")
+# scopes below the stages (``repro.models.model``): the cast of float32
+# master weights to the compute dtype, and the client model's attention
+# core, feed-forward and vocabulary head with the cross-entropy. Read by
+# :func:`scope_seconds`; :func:`stage_seconds` still counts their time in
+# the stage around them.
+SUBSCOPES = ("fed.cast", "lm.attn", "lm.mlp", "lm.head")
 OTHER = "other"
 # ops that hold other ops: their device time is their children's, so
 # counting them too would count that time twice
@@ -96,7 +102,7 @@ def last(name: str, n: int, spans: Sequence[Span]) -> List[Span]:
 
 _INSTR = re.compile(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
-_STAGE = re.compile(r"fed\.[a-z_]+")
+_STAGE = re.compile(r"(?:fed|lm)\.[a-z_]+")
 
 
 def _parse(text: str) -> Optional[Tuple[str, str, str, str]]:
@@ -124,15 +130,16 @@ def _parse(text: str) -> Optional[Tuple[str, str, str, str]]:
             f"{name} = {rest.strip()}")
 
 
-def stage_of_op_name(op_name: str) -> str:
-    """The innermost :data:`STAGES` component of an HLO ``op_name``."""
-    found = [s for s in _STAGE.findall(op_name) if s in STAGES]
+def stage_of_op_name(op_name: str, scopes: Sequence[str] = STAGES) -> str:
+    """The innermost of ``scopes`` (by default :data:`STAGES`) among the
+    components of an HLO ``op_name``, or :data:`OTHER`."""
+    found = [s for s in _STAGE.findall(op_name) if s in scopes]
     return found[-1] if found else OTHER
 
 
-def _hlo_index(hlo_texts: Iterable[str]
+def _hlo_index(hlo_texts: Iterable[str], scopes: Sequence[str]
                ) -> Dict[str, List[Tuple[str, str, str]]]:
-    """Instruction name -> ``[(signature, text, stage)]`` over the compiled
+    """Instruction name -> ``[(signature, text, scope)]`` over the compiled
     HLO modules ``hlo_texts`` (a name repeats only across modules)."""
     index: Dict[str, List[Tuple[str, str, str]]] = {}
     for text in hlo_texts:
@@ -142,20 +149,21 @@ def _hlo_index(hlo_texts: Iterable[str]
                 continue
             name, _, sig, instr = parsed
             m = _OP_NAME.search(line)
-            stage = stage_of_op_name(m.group(1)) if m else OTHER
+            stage = stage_of_op_name(m.group(1), scopes) if m else OTHER
             index.setdefault(name, []).append((sig, instr, stage))
     return index
 
 
-def attribute(device_ops: Iterable[Tuple[str, float]], hlo_texts
+def attribute(device_ops: Iterable[Tuple[str, float]], hlo_texts,
+              scopes: Sequence[str] = STAGES
               ) -> List[Tuple[str, Optional[str], float]]:
     """``(op, stage, seconds)`` for every device op that is not a container.
     An op matches an instruction of ``hlo_texts`` with its name, result
     shape and opcode; where no instruction matches (an op of another
     program whose name happens to repeat, say), ``stage`` is None. Where
     modules share a match, the instruction whose text agrees with the op's
-    longest decides."""
-    index = _hlo_index(hlo_texts)
+    longest decides. ``stage`` is the innermost of ``scopes``."""
+    index = _hlo_index(hlo_texts, scopes)
     out = []
     for op, seconds in device_ops:
         parsed = _parse(op)
@@ -185,5 +193,17 @@ def stage_seconds(device_ops: Iterable[Tuple[str, float]], hlo_texts
     out: Dict[str, float] = {}
     for _, stage, seconds in attribute(device_ops, hlo_texts):
         key = stage or OTHER
+        out[key] = out.get(key, 0.0) + seconds
+    return out
+
+
+def scope_seconds(device_ops: Iterable[Tuple[str, float]], hlo_texts,
+                  scopes: Sequence[str] = SUBSCOPES) -> Dict[str, float]:
+    """Device self seconds by the innermost of ``scopes`` (by default
+    :data:`SUBSCOPES`) in each op's ``op_name``, as :func:`stage_seconds`
+    reads stages; the rest goes to :data:`OTHER`."""
+    out: Dict[str, float] = {}
+    for _, scope, seconds in attribute(device_ops, hlo_texts, scopes):
+        key = scope or OTHER
         out[key] = out.get(key, 0.0) + seconds
     return out

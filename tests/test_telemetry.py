@@ -193,6 +193,49 @@ def test_stage_of_op_name_takes_the_innermost_stage(op_name, stage):
     assert telemetry.stage_of_op_name(op_name) == stage
 
 
+@pytest.mark.parametrize("op_name, scope", [
+    ("jit(f)/fed.local_train/fed.cast/convert_element_type", "fed.cast"),
+    ("jit(f)/transpose(jvp(fed.local_train))/while/body/checkpoint/lm.attn/"
+     "dot_general", "lm.attn"),
+    ("jit(f)/fed.local_train/lm.head/while/body/dot_general", "lm.head"),
+    ("jit(f)/fed.eval/while/body/lm.mlp/mul", "lm.mlp"),
+    ("jit(f)/fed.local_train/while/body/add", "other"),
+])
+def test_scopes_below_the_stages(op_name, scope):
+    """The client model's scopes and the master cast are read below the
+    stage that holds them, which keeps its time for ``stage_seconds``."""
+    assert telemetry.stage_of_op_name(op_name, telemetry.SUBSCOPES) == scope
+    assert telemetry.stage_of_op_name(op_name) in telemetry.STAGES + (
+        "other",)
+
+
+def test_scope_seconds_on_a_compiled_model_loss():
+    """A bfloat16-compute transformer's loss from float32 masters, compiled
+    with its gradient: every scope below the stages shows up, and the
+    split adds up to every non-container op."""
+    import dataclasses
+
+    from repro.configs import get_config, reduced
+    from repro.models import model as lm
+
+    cfg = dataclasses.replace(reduced(get_config("smollm-135m"), d_model=32,
+                                      layers=1), dtype="bfloat16")
+    params = lm.init_params(jax.random.PRNGKey(0), lm.master_config(cfg))
+    toks = jnp.zeros((1, 16), jnp.int32)
+
+    def step(p):
+        with jax.named_scope("fed.local_train"):
+            return jax.grad(lambda q: lm.loss_fn(
+                q, cfg, {"tokens": toks, "labels": toks}, ce_chunk=8))(p)
+
+    text = jax.jit(step).lower(params).compile().as_text()
+    ops = [(_profile_name(line), 1.0) for line, op, _ in _instructions(text)
+           if op not in telemetry.CONTAINERS]
+    got = telemetry.scope_seconds(ops, [text])
+    assert set(telemetry.SUBSCOPES) <= set(got)
+    assert sum(got.values()) == len(ops)
+
+
 def test_round_scan_carries_every_stage(hlo):
     """The mlp-family programs: the scan carries all six stage names, and
     every gather and dot in it falls in one."""
